@@ -31,6 +31,7 @@ import sys
 import time
 from typing import Callable, Dict, List, Tuple
 
+from repro.compile_cache import use_compile_cache
 from repro.core import (
     ContentionParams,
     PAPER_A,
@@ -1125,6 +1126,7 @@ def main() -> None:
         "--policy/--seeds pick the (single) comm policy and seed",
     )
     args = ap.parse_args()
+    use_compile_cache()
     if args.trace_out:
         export_traces(
             args.trace_out,

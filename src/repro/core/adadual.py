@@ -27,6 +27,7 @@ import math
 from typing import List, Sequence, Tuple
 
 from repro.core import netmodel
+from repro.core.cluster import left_sum
 from repro.core.contention import ContentionParams
 
 # ---------------------------------------------------------------------------
@@ -243,7 +244,7 @@ def kway_lookahead_costs(
     now = [0.0] * (k + 1)
     sizes_a = list(olds) + [new_bytes]
     fin_a = simulate_task_set(now, sizes_a, params)
-    avg_a = sum(fin_a) / len(fin_a)
+    avg_a = left_sum(fin_a) / len(fin_a)
 
     # Option B: olds run contended among themselves; new starts when the first
     # old finishes, then (recursively) contends with the survivors.
@@ -263,7 +264,7 @@ def kway_lookahead_costs(
     # olds that finished at t_first (ties with the smallest included):
     n_done = k - len(survivors)
     avg_b = (
-        n_done * t_first + sum(t_first + f for f in fin_b_rel)
+        n_done * t_first + left_sum(t_first + f for f in fin_b_rel)
     ) / (n_done + len(fin_b_rel))
     return avg_a, avg_b
 

@@ -11,7 +11,18 @@ jobs that span servers (Eq. 5).
 from __future__ import annotations
 
 import dataclasses
-from typing import Dict, List, Optional, Sequence, Set, Tuple
+from typing import Dict, Iterable, List, Optional, Sequence, Set, Tuple
+
+
+def left_sum(xs: Iterable[float]) -> float:
+    """Plain left-to-right sum.  Builtin ``sum()`` compensates float
+    rounding from Python 3.12 on; the simulators fold explicitly so their
+    results do not depend on the interpreter version."""
+    total = 0
+    for x in xs:
+        total += x
+    return total
+
 
 # ---------------------------------------------------------------------------
 # Job descriptions
@@ -216,7 +227,7 @@ class Cluster:
 
     def server_workload(self, server: int) -> float:
         """L_{S_i} = sum_j L_{g_{i,j}}."""
-        return sum(g.workload for g in self.gpus_of_server(server))
+        return left_sum(g.workload for g in self.gpus_of_server(server))
 
     #: when True, a GPU may host at most one job (paper assumption 3:
     #: "Each GPU can only be occupied by one job at any time slot"); when

@@ -57,7 +57,7 @@ from __future__ import annotations
 
 from typing import Dict, List, Optional, Sequence, Set, Tuple
 
-from repro.core.cluster import Cluster, GpuId
+from repro.core.cluster import Cluster, GpuId, left_sum
 
 __all__ = ["ClusterIndex"]
 
@@ -193,7 +193,7 @@ class ClusterIndex:
 
     def rack_loads(self, racks: Sequence[Sequence[int]]) -> List[float]:
         """Per-rack workload sums for the given rack grouping —
-        value-identical to ``[sum(load[s] for s in rack) for rack in racks]``."""
+        value-identical to ``[left_sum(load[s] for s in rack) for rack in racks]``."""
         if self._racks is None or racks != self._racks:
             self._set_racks(racks)
         load = self.server_loads()  # flush servers before racks
@@ -201,7 +201,7 @@ class ClusterIndex:
             rl = self._rack_load
             rk = self._racks
             for r in self._dirty_racks:
-                rl[r] = sum(load[s] for s in rk[r])
+                rl[r] = left_sum(load[s] for s in rk[r])
             self._dirty_racks.clear()
         return self._rack_load
 
@@ -269,7 +269,7 @@ class ClusterIndex:
         if self._racks is not None:
             rl = self.rack_loads(self._racks)
             for ri, rack in enumerate(self._racks):
-                want = sum(loads[s] for s in rack)
+                want = left_sum(loads[s] for s in rack)
                 assert rl[ri] == want, f"rack {ri}: load {rl[ri]!r} != {want!r}"
         for s in range(cluster.n_servers):
             mf, mfe = self.server_feas(s)

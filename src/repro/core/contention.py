@@ -27,6 +27,8 @@ from typing import Tuple
 
 import numpy as np
 
+from repro.core.cluster import left_sum
+
 # ---------------------------------------------------------------------------
 # Measured constants (paper Section III-A2, Fig. 2(a); 10 GbE, ring all-reduce)
 # ---------------------------------------------------------------------------
@@ -97,7 +99,7 @@ class ContentionParams:
         if not self.server_bandwidth or n_servers <= 0:
             return 1.0
         n = len(self.server_bandwidth)
-        return sum(
+        return left_sum(
             (self.server_bandwidth[s] if s < n else 1.0) for s in range(n_servers)
         ) / n_servers
 

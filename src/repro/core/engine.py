@@ -71,7 +71,7 @@ from repro.core.chaos import (
     nic_degradation_stream,
     server_failure_stream,
 )
-from repro.core.cluster import Cluster, GpuId, JobSpec
+from repro.core.cluster import Cluster, GpuId, JobSpec, left_sum
 from repro.core.clusterindex import ClusterIndex
 from repro.core.contention import ContentionParams
 from repro.core.trace import TraceSource
@@ -331,7 +331,7 @@ class SimResult:
     obs: Optional[object] = None
 
     def avg_jct(self) -> float:
-        return sum(self.jct.values()) / len(self.jct)
+        return left_sum(self.jct.values()) / len(self.jct)
 
     def median_jct(self) -> float:
         return median(list(self.jct.values()))
@@ -399,7 +399,7 @@ class SimResult:
                     "jobs_per_sec": len(jcts) / window_s,
                     "p99_jct": percentile(jcts, 0.99),
                     "queueing_delay_mean": (
-                        sum(qds) / len(qds) if qds else math.nan
+                        left_sum(qds) / len(qds) if qds else math.nan
                     ),
                 }
             )
@@ -438,7 +438,7 @@ class SimResult:
             "sustained_goodput": median([w["goodput"] for w in body]),
             "sustained_jobs_per_sec": median([w["jobs_per_sec"] for w in body]),
             "p99_jct": percentile(jcts, 0.99),
-            "queueing_delay_mean": sum(qds) / len(qds) if qds else math.nan,
+            "queueing_delay_mean": left_sum(qds) / len(qds) if qds else math.nan,
             "queueing_delay_p99": percentile(qds, 0.99),
         }
 
@@ -2120,9 +2120,9 @@ class EventEngine:
             # live jobs (runs + requeued carries).  Cancelled jobs left the
             # system with their partial progress — not delivered, not
             # counted.
-            delivered = sum(r.samples_done for r in self._runs.values()) + sum(
-                c.samples_done for c in self._carry.values()
-            )
+            delivered = left_sum(
+                r.samples_done for r in self._runs.values()
+            ) + left_sum(c.samples_done for c in self._carry.values())
         else:
             # Streaming mode: finished runs were retired as the replay went,
             # so the finish-time records are the only copy (finish order).
@@ -2130,14 +2130,14 @@ class EventEngine:
             finish = self._finish_at
             qdelay = self._qdelay_at_finish
             delivered = (
-                sum(self._job_samples.values())
-                + sum(r.samples_done for r in self._runs.values())
-                + sum(c.samples_done for c in self._carry.values())
+                left_sum(self._job_samples.values())
+                + left_sum(r.samples_done for r in self._runs.values())
+                + left_sum(c.samples_done for c in self._carry.values())
             )
         makespan = max(finish.values()) if finish else now
         busy = {gid: g.busy_accum for gid, g in self.cluster.gpus.items()}
         util = (
-            sum(busy.values()) / (len(busy) * makespan) if makespan > 0 else 0.0
+            left_sum(busy.values()) / (len(busy) * makespan) if makespan > 0 else 0.0
         )
         obs_report = None
         if self._obs is not None:

@@ -60,9 +60,8 @@ ticks.  It is a *segmented* driver:
 * **Fused step core** — the per-tick contention/rate evaluation (domain
   incidence matmuls, Eq. 5 rate, slowest-member scale, gating-side
   ``k_would``/``min_old_rem``) is one call into
-  ``repro.kernels.fluidstep`` with a lax reference path (default, CPU CI)
-  and an optional Pallas kernel (``cfg.kernel`` / ``REPRO_FLUID_KERNEL``
-  = ``"interpret"`` | ``"tpu"``).
+  ``repro.kernels.fluidstep``: the compiled Pallas kernel on a TPU, the
+  lax reference elsewhere (``cfg.kernel`` names one explicitly).
 
 Remaining approximations vs the event simulator (``core/simulator.py``),
 all documented and tested for *qualitative* agreement:
@@ -124,7 +123,11 @@ class JaxSimConfig:
     n_servers: int = 16
     gpus_per_server: int = 4
     dt: float = 0.05          # [s]
-    max_steps: int = 400_000  # dt * max_steps = simulated horizon cap
+    #: dt * max_steps = simulated horizon cap (100,000 s at the default
+    #: dt, several times the paper trace's longest makespan).  A lane
+    #: still running at the cap returns unfinished jobs; the scenario
+    #: Monte-Carlo entry point raises on it.
+    max_steps: int = 2_000_000
     policy: str = "ada"       # ada | srsfN | kwayK (netmodel.parse_policy)
     #: consolidate | first_fit | least_loaded | random | rack_pack
     placement: str = "consolidate"
@@ -154,7 +157,8 @@ class JaxSimConfig:
     skip: bool = True
     #: retire finished lanes / trim padding between chunks.
     compact: bool = True
-    #: fluid step core impl ("" = REPRO_FLUID_KERNEL env, default "ref").
+    #: fluid step core impl: "ref" | "interpret" | "tpu"; "" = "tpu" when
+    #: the default device is a TPU, else "ref" (resolved per launch).
     kernel: str = ""
 
     def __post_init__(self) -> None:
@@ -237,13 +241,17 @@ _EXACT_KWAY_POLICY = "<exact-kway>"
 def _policy_args(cfg: JaxSimConfig):
     """(max_ways, threshold_gated) as arrays + the policy-stripped static
     config key; threshold policies (ada/srsfN) all share one compiled
-    graph, exact-lookahead ``kwayK`` policies share another."""
+    graph, exact-lookahead ``kwayK`` policies share another.  The key
+    carries the resolved step-core impl, so a launch under another
+    default device never reuses a graph built for a different kernel."""
     spec = netmodel.parse_policy(cfg.policy)
     sentinel = _EXACT_KWAY_POLICY if spec.exact_lookahead else _DYNAMIC_POLICY
     return (
         jnp.asarray(spec.max_ways, jnp.float32),
         jnp.asarray(spec.threshold_gated, bool),
-        dataclasses.replace(cfg, policy=sentinel),
+        dataclasses.replace(
+            cfg, policy=sentinel, kernel=fluidstep.resolve_impl(cfg.kernel)
+        ),
     )
 
 
@@ -719,7 +727,7 @@ def _drive_batched(traces: Dict[str, jnp.ndarray], cfg: JaxSimConfig,
     """Host driver: chunked scan segments with early exit and (optional)
     lane/job/bucket compaction.  ``cfg`` is the policy-stripped static
     key from :func:`_policy_args`.  Returns numpy result planes shaped
-    like the input batch."""
+    like the input batch, plus ``chunks``: the scan segments launched."""
     arrival0 = np.asarray(traces["arrival"], np.float32)
     n_lanes0, n_jobs0 = arrival0.shape
     if "valid" not in traces:
@@ -730,12 +738,14 @@ def _drive_batched(traces: Dict[str, jnp.ndarray], cfg: JaxSimConfig,
         "jct": np.full((n_lanes0, n_jobs0), np.inf, np.float32),
         "finished": np.zeros((n_lanes0, n_jobs0), bool),
         "makespan": np.zeros((n_lanes0,), np.float32),
+        "chunks": 0,
     }
     orig = np.arange(n_lanes0)  # current lane -> original row (-1 = retired)
     state = _init_jit(traces, cfg)
 
     while True:
         state = _chunk_jit(traces, state, cfg, max_ways, gated)
+        results["chunks"] += 1
         n_jobs_cur = int(traces["arrival"].shape[1])
         n_done = np.asarray(state["n_done"])
         tick = np.asarray(state["i"])
@@ -840,10 +850,10 @@ def simulate_trace(trace: Dict[str, jnp.ndarray], cfg: JaxSimConfig):
 def simulate_traces_batched(traces: Dict[str, jnp.ndarray], cfg: JaxSimConfig):
     """Chunked-scan launches over a stacked batch of traces (leading axis
     = seed; see :func:`stack_traces`).  Returns per-lane jct/finished
-    arrays and a per-lane makespan vector — the scenario Monte-Carlo
-    entry point.  Policy-dynamic like :func:`simulate_trace`; finished
-    lanes retire between chunks (``cfg.compact``) so stragglers don't pay
-    full batch width."""
+    arrays, a per-lane makespan vector and the number of scan chunks
+    launched — the scenario Monte-Carlo entry point.  Policy-dynamic like
+    :func:`simulate_trace`; finished lanes retire between chunks
+    (``cfg.compact``) so stragglers don't pay full batch width."""
     max_ways, gated, cfg_key = _policy_args(cfg)
     out = _drive_batched(
         {k: jnp.asarray(v) for k, v in traces.items()}, cfg_key, max_ways, gated
@@ -852,6 +862,7 @@ def simulate_traces_batched(traces: Dict[str, jnp.ndarray], cfg: JaxSimConfig):
         "jct": jnp.asarray(out["jct"]),
         "finished": jnp.asarray(out["finished"]),
         "makespan": jnp.asarray(out["makespan"]),
+        "chunks": out["chunks"],
     }
 
 

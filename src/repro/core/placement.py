@@ -28,7 +28,7 @@ import heapq
 import random
 from typing import List, Optional, Sequence, Tuple
 
-from repro.core.cluster import Cluster, GpuId, GpuState, JobSpec
+from repro.core.cluster import Cluster, GpuId, GpuState, JobSpec, left_sum
 from repro.core.topology import Topology
 
 
@@ -128,7 +128,7 @@ def place_lwf_rack(
     load = [cluster.server_workload(s) for s in range(cluster.n_servers)]
     rack_order = sorted(
         range(len(racks)),
-        key=lambda r: (sum(load[s] for s in racks[r]), r),
+        key=lambda r: (left_sum(load[s] for s in racks[r]), r),
     )
     ordered: List[GpuState] = []
     for r in rack_order:

@@ -1,8 +1,8 @@
 # Fused per-step contention/rate core of the fluid simulator's hot loop
 # (domain incidence matmuls, Eq. 5 rate, slowest-member scale, gating-side
-# k/min-old-rem).  The reference lax composition is the default everywhere
-# (CPU CI included); the Pallas kernel is opt-in via REPRO_FLUID_KERNEL or
-# JaxSimConfig.kernel ("interpret" | "tpu").
-from repro.kernels.fluidstep.ops import FLUID_KERNEL_ENV, fluid_step_core
+# k/min-old-rem).  JaxSimConfig.kernel picks the implementation; left
+# empty it is the compiled Pallas kernel on a TPU and the lax reference
+# everywhere else.
+from repro.kernels.fluidstep.ops import fluid_step_core, resolve_impl
 
-__all__ = ["FLUID_KERNEL_ENV", "fluid_step_core"]
+__all__ = ["fluid_step_core", "resolve_impl"]

@@ -78,7 +78,7 @@ def _fluid_step_kernel(loads_ref, member_ref, active_ref, rem_ref, bw_ref,
     jax.jit, static_argnames=("b", "eta", "interpret")
 )
 def fluid_step_core_pallas(loads, member, active, rem, bw, oversub, *,
-                           b: float, eta: float, interpret: bool = True):
+                           b: float, eta: float, interpret: bool):
     """Run the fused step core; returns raw float planes (see ops.py)."""
     n_jobs = member.shape[0]
     n_domains = loads.shape[1]

@@ -2,37 +2,55 @@
 
 ``fluid_step_core`` is the single entry point the fluid simulator's hot
 loop calls once per executed tick.  The implementation is chosen by the
-``impl`` argument, defaulting to the ``REPRO_FLUID_KERNEL`` environment
-variable and finally to ``"ref"``:
+``impl`` argument (``JaxSimConfig.kernel``):
 
-* ``ref``       — the historical lax composition (ref.py).  Default
-                  everywhere, including CPU CI: XLA fuses it fine and it
-                  is the bit-exactness anchor.
-* ``interpret`` — the Pallas kernel in interpreter mode (runs on CPU;
-                  used by the parity test, and useful for debugging).
-* ``tpu``       — the compiled Pallas kernel (real TPU hardware).
-
-The flag is read at trace time (the simulator jit-retraces per config),
-so flipping the env var between calls behaves as expected.
+* ``""``        — the backend's own: ``tpu`` when the default device is a
+                  TPU, ``ref`` otherwise (:func:`default_impl`).
+* ``ref``       — the historical lax composition (ref.py), the
+                  bit-exactness anchor.
+* ``interpret`` — the Pallas kernel in interpreter mode (runs on CPU; only
+                  the parity tests name it).
+* ``tpu``       — the compiled Pallas kernel; raises on any other backend.
 """
 
 from __future__ import annotations
 
-import os
-
+import jax
 import jax.numpy as jnp
 
 from repro.kernels.fluidstep.kernel import _BIG, fluid_step_core_pallas
 from repro.kernels.fluidstep.ref import fluid_step_core_ref
 
-#: Environment variable selecting the implementation ("ref" default).
-FLUID_KERNEL_ENV = "REPRO_FLUID_KERNEL"
-
 FLUID_KERNEL_IMPLS = ("ref", "interpret", "tpu")
 
 
+def backend_platform() -> str:
+    """Platform of the default device (honours ``jax.default_device``)."""
+    dev = jax.config.jax_default_device
+    if dev is None:
+        return jax.default_backend()
+    return dev if isinstance(dev, str) else dev.platform
+
+
 def default_impl() -> str:
-    return os.environ.get(FLUID_KERNEL_ENV, "ref") or "ref"
+    return "tpu" if backend_platform() == "tpu" else "ref"
+
+
+def resolve_impl(impl: str) -> str:
+    """Validate ``impl`` and resolve ``""`` to :func:`default_impl`."""
+    impl = impl or default_impl()
+    if impl not in FLUID_KERNEL_IMPLS:
+        raise ValueError(
+            f"unknown fluid step impl {impl!r}; expected one of "
+            f"{FLUID_KERNEL_IMPLS}"
+        )
+    if impl == "tpu" and backend_platform() != "tpu":
+        raise ValueError(
+            f"fluid step impl 'tpu' needs a TPU backend, but the default "
+            f"device is {backend_platform()!r}; use 'ref' (or 'interpret' "
+            "to run the kernel body on the CPU)"
+        )
+    return impl
 
 
 def fluid_step_core(loads, member, active, rem, bw, oversub, *,
@@ -41,19 +59,14 @@ def fluid_step_core(loads, member, active, rem, bw, oversub, *,
     """Contention/rate core of one fluid step (see ref.py for semantics).
 
     ``loads`` is the precomputed ``(J, D)`` domain-load mask (maintained
-    incrementally by the simulator).  ``impl`` = "" resolves through
-    :data:`FLUID_KERNEL_ENV`; outputs are dtype-identical across
+    incrementally by the simulator).  ``impl`` goes through
+    :func:`resolve_impl`; outputs are dtype-identical across
     implementations (counts/k_would int32, rates float32, absent-old
     sentinel mapped back to +inf).  ``overlap`` is None when
     ``need_overlap`` is False on the reference path; the Pallas kernel
     computes it unconditionally (one MXU matmul, free on TPU).
     """
-    impl = impl or default_impl()
-    if impl not in FLUID_KERNEL_IMPLS:
-        raise ValueError(
-            f"unknown fluid step impl {impl!r}; expected one of "
-            f"{FLUID_KERNEL_IMPLS}"
-        )
+    impl = resolve_impl(impl)
     if impl == "ref":
         return fluid_step_core_ref(
             loads, member, active, rem, bw, oversub,

@@ -26,9 +26,9 @@ is O(J·D) with no J×J intermediate.  The overlap matrix itself is only
 materialized when ``need_overlap`` (WFBP gating closure / exact k-way
 lookahead paths).
 
-Keeping this path the default (CPU CI, all tests) means the fast-path
-refactor cannot drift the physics: the kernel is an optional accelerator,
-not a second source of truth.
+This path is the default on every backend but the TPU (CPU CI, all
+differential tests), so the fast-path refactor cannot drift the physics:
+the kernel is an accelerator, not a second source of truth.
 """
 
 from __future__ import annotations

@@ -8,6 +8,7 @@ before any jax import; tests and benches see the real single device).
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
 #: TPU v5e hardware constants used by the roofline analysis.
 PEAK_FLOPS_BF16 = 197e12      # per chip [FLOP/s]
@@ -19,36 +20,17 @@ SINGLE_POD_SHAPE = (16, 16)
 MULTI_POD_SHAPE = (2, 16, 16)
 
 
-def make_mesh_compat(shape, axes) -> jax.sharding.Mesh:
-    """``jax.make_mesh`` across jax versions: ``axis_types`` (and the
-    ``AxisType`` enum itself) only exist on newer releases; all axes are
-    Auto there, which is also the older releases' only behaviour."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is not None:
-        return jax.make_mesh(shape, axes, axis_types=(axis_type.Auto,) * len(axes))
-    return jax.make_mesh(shape, axes)
-
-
-def make_abstract_mesh(shape, axes):
-    """``jax.sharding.AbstractMesh`` across jax versions: newer releases take
-    ``(shape, axis_names)``, older ones a single ``(("name", size), ...)``."""
-    from jax.sharding import AbstractMesh
-
-    try:
-        return AbstractMesh(tuple(shape), tuple(axes))
-    except TypeError:
-        return AbstractMesh(tuple(zip(axes, shape)))
-
-
 def make_production_mesh(*, multi_pod: bool = False) -> jax.sharding.Mesh:
     shape = MULTI_POD_SHAPE if multi_pod else SINGLE_POD_SHAPE
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return make_mesh_compat(shape, axes)
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_host_mesh(data: int = 1, model: int = 1) -> jax.sharding.Mesh:
     """Small mesh over whatever devices exist (CPU tests)."""
-    return make_mesh_compat((data, model), ("data", "model"))
+    return jax.make_mesh(
+        (data, model), ("data", "model"), axis_types=(AxisType.Auto,) * 2
+    )
 
 
 def n_chips(mesh: jax.sharding.Mesh) -> int:

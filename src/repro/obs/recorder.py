@@ -65,6 +65,8 @@ import dataclasses
 import math
 from typing import Dict, List, Optional, Tuple
 
+from repro.core.cluster import left_sum
+
 #: column order of ``ObsReport.decomposition_csv`` rows
 DECOMP_CSV_FIELDS = (
     "job_id",
@@ -763,12 +765,12 @@ class ObsReport:
     def mean_stretch_frac(self) -> float:
         if not self.decomp:
             return math.nan
-        return sum(p.stretch_frac for p in self.decomp.values()) / len(self.decomp)
+        return left_sum(p.stretch_frac for p in self.decomp.values()) / len(self.decomp)
 
     def mean_gating_frac(self) -> float:
         if not self.decomp:
             return math.nan
-        return sum(p.gating_frac for p in self.decomp.values()) / len(self.decomp)
+        return left_sum(p.gating_frac for p in self.decomp.values()) / len(self.decomp)
 
     def mean_parts(self) -> Dict[str, float]:
         """Mean seconds per decomposition bucket over finished jobs."""

@@ -18,6 +18,7 @@ import dataclasses
 import math
 from typing import Dict, List, Optional, Sequence, Tuple
 
+from repro.core.cluster import left_sum
 from repro.core.engine import SimResult, median, percentile
 
 CSV_FIELDS = (
@@ -97,6 +98,9 @@ class RunMetrics:
     #: (``observe=None``) — absent data, not zero.
     stretch_frac: float = math.nan
     gating_frac: float = math.nan
+    #: fluid backend: scan chunks the batched driver launched for the
+    #: batch this run was part of (0 = event backend)
+    chunks: int = 0
 
     def as_csv_row(self) -> str:
         vals = []
@@ -141,6 +145,7 @@ def from_jcts(
     peak_calendar: int = 0,
     stretch_frac: float = math.nan,
     gating_frac: float = math.nan,
+    chunks: int = 0,
 ) -> RunMetrics:
     jcts = [float(x) for x in jcts]
     n_fin = len(jcts)
@@ -152,7 +157,7 @@ def from_jcts(
         seed=seed,
         n_jobs=n_jobs,
         n_finished=n_fin,
-        avg_jct=(sum(jcts) / n_fin) if n_fin else math.nan,
+        avg_jct=(left_sum(jcts) / n_fin) if n_fin else math.nan,
         median_jct=median(jcts),
         p95_jct=percentile(jcts, 0.95),
         makespan=float(makespan),
@@ -172,6 +177,7 @@ def from_jcts(
         peak_calendar=peak_calendar,
         stretch_frac=stretch_frac,
         gating_frac=gating_frac,
+        chunks=chunks,
     )
 
 
@@ -289,8 +295,8 @@ class CellCI:
 def _mean_std(xs: Sequence[float]) -> Tuple[float, float]:
     if not xs:
         return math.nan, math.nan
-    mu = sum(xs) / len(xs)
-    var = sum((x - mu) ** 2 for x in xs) / len(xs)
+    mu = left_sum(xs) / len(xs)
+    var = left_sum((x - mu) ** 2 for x in xs) / len(xs)
     return mu, math.sqrt(var)
 
 
@@ -319,7 +325,7 @@ def ci_from_runs(records: Sequence[RunMetrics]) -> List[CellCI]:
                 makespan_std=mk_sd,
                 finished_frac=sum(r.n_finished for r in rs)
                 / max(1, sum(r.n_jobs for r in rs)),
-                wall_s=sum(r.wall_s for r in rs),
+                wall_s=left_sum(r.wall_s for r in rs),
             )
         )
     return out
@@ -336,10 +342,10 @@ def summarize(records: Sequence[RunMetrics]) -> Dict[str, Dict[str, float]]:
     out: Dict[str, Dict[str, float]] = {}
     for key, rs in sorted(groups.items()):
         out[key] = {
-            "avg_jct": sum(r.avg_jct for r in rs) / len(rs),
-            "p95_jct": sum(r.p95_jct for r in rs) / len(rs),
-            "makespan": sum(r.makespan for r in rs) / len(rs),
-            "gpu_util": sum(r.gpu_util for r in rs) / len(rs),
+            "avg_jct": left_sum(r.avg_jct for r in rs) / len(rs),
+            "p95_jct": left_sum(r.p95_jct for r in rs) / len(rs),
+            "makespan": left_sum(r.makespan for r in rs) / len(rs),
+            "gpu_util": left_sum(r.gpu_util for r in rs) / len(rs),
             "finished_frac": sum(r.n_finished for r in rs)
             / max(1, sum(r.n_jobs for r in rs)),
             "n_runs": float(len(rs)),

@@ -45,6 +45,11 @@ COMM_ALIASES = {
 #: shared layer): AdaDUAL, SRSF(n), and threshold-gated k-way AdaDUAL.
 FLUID_POLICIES = ("ada", "srsf1", "srsf2", "srsf3", "kway2", "kway3")
 
+#: Fluid-vs-event agreement bound on aggregate metrics (avg JCT, makespan):
+#: each backend's value within this factor of the other's.  Gang placement
+#: makes the fluid backend pessimistic on shared-GPU scenarios.
+FLUID_EVENT_RATIO = 2.0
+
 
 def canonical_comm(comm: str) -> str:
     return COMM_ALIASES.get(comm.lower(), comm.lower())
@@ -102,7 +107,7 @@ def fluid_config(
     comm: str = "ada",
     placement: str = "lwf",
     dt: float = 0.05,
-    max_steps: int = 400_000,
+    max_steps: Optional[int] = None,
     **fast_kw,
 ):
     """JaxSimConfig for a scenario: per-server bandwidth and the fabric
@@ -112,7 +117,8 @@ def fluid_config(
     (lwf->consolidate, ff->first_fit, ls->least_loaded, rand->random,
     lwf_rack->rack_pack).  ``fast_kw`` forwards the fast-path knobs
     (``skip``, ``gating``, ``compact``, ``chunk_steps``, ``kernel``) —
-    how the equivalence tests pin e.g. ``gating="rounds", skip=False``."""
+    how the equivalence tests pin e.g. ``gating="rounds", skip=False``.
+    ``max_steps=None`` keeps the config's horizon cap."""
     from repro.core.jaxsim import JaxSimConfig
 
     comm = canonical_comm(comm)
@@ -133,13 +139,14 @@ def fluid_config(
             "only: the fluid backend needs the whole trace as one static "
             "tensor, defeating the O(live jobs) replay memory bound"
         )
+    if max_steps is not None:
+        fast_kw["max_steps"] = max_steps
     p = scenario.params
     gang_mode = netmodel.canonical_placement(placement)
     return JaxSimConfig(
         n_servers=scenario.n_servers,
         gpus_per_server=scenario.gpus_per_server,
         dt=dt,
-        max_steps=max_steps,
         policy=comm,
         placement=gang_mode,
         a=p.a,
@@ -160,7 +167,7 @@ def run_scenario_fluid(
     comm: str = "ada",
     placement: str = "lwf",
     dt: float = 0.05,
-    max_steps: int = 400_000,
+    max_steps: Optional[int] = None,
     **fast_kw,
 ) -> Dict[str, object]:
     """Fluid (vectorized JAX) simulation of one scenario instance (the
@@ -211,14 +218,14 @@ class SweepCell:
 
 
 def run_cell(cell: SweepCell) -> metrics_mod.RunMetrics:
-    scn = get_scenario(cell.scenario, seed=cell.seed, **dict(cell.overrides))
     if cell.sim_kw and cell.backend != "event":
         raise ValueError(
             f"sim_kw {dict(cell.sim_kw)} is event-backend only "
             f"(got backend {cell.backend!r})"
         )
-    t0 = time.time()
     if cell.backend == "event":
+        scn = get_scenario(cell.scenario, seed=cell.seed, **dict(cell.overrides))
+        t0 = time.time()
         res = run_scenario_event(
             scn,
             placement=cell.placement,
@@ -234,21 +241,12 @@ def run_cell(cell: SweepCell) -> metrics_mod.RunMetrics:
             wall_s=time.time() - t0,
         )
     if cell.backend == "fluid":
-        out = run_scenario_fluid(
-            scn, comm=cell.comm, placement=cell.placement, dt=cell.dt
+        (rec,) = monte_carlo_fluid(
+            cell.scenario, [cell.seed], comm=cell.comm,
+            placement=cell.placement, overrides=dict(cell.overrides),
+            dt=cell.dt,
         )
-        jcts = [float(j) for j, fin in zip(out["jct"], out["finished"]) if fin]
-        return metrics_mod.from_jcts(
-            jcts,
-            scenario=cell.scenario,
-            backend="fluid",
-            placement=f"gang-{netmodel.canonical_placement(cell.placement)}",
-            comm=canonical_comm(cell.comm),
-            seed=cell.seed,
-            n_jobs=scn.n_jobs,
-            makespan=out["makespan"],
-            wall_s=time.time() - t0,
-        )
+        return rec
     raise ValueError(f"unknown backend {cell.backend!r}")
 
 
@@ -322,7 +320,7 @@ def monte_carlo_fluid(
     placement: str = "lwf",
     overrides: Optional[Dict[str, object]] = None,
     dt: float = 0.05,
-    max_steps: int = 400_000,
+    max_steps: Optional[int] = None,
     **fast_kw,
 ) -> List[metrics_mod.RunMetrics]:
     """All seeds of one scenario x policy x placement cell in ONE vmapped
@@ -337,7 +335,11 @@ def monte_carlo_fluid(
     per chunk, retiring finished lanes and trimming the job axis down to
     the widest *live* lane after each compaction, so one long-tailed seed
     no longer drags the whole batch at max width (the old driver ran every
-    lane at the global max shape for every step)."""
+    lane at the global max shape for every step).
+
+    Raises ``RuntimeError`` if any lane reaches the horizon cap
+    (``max_steps`` ticks) with jobs unfinished: its JCT statistics would
+    cover only the jobs that finished and read as a fast run."""
     import numpy as np
 
     from repro.core.jaxsim import (
@@ -361,6 +363,19 @@ def monte_carlo_fluid(
     fin = np.asarray(out["finished"])
     mks = np.asarray(out["makespan"])
     wall = (time.time() - t0) / len(seeds)
+    stranded = [
+        (seed, scn.n_jobs - int(fin[i].sum()))
+        for i, (seed, scn) in enumerate(zip(seeds, scns))
+        if fin[i].sum() != scn.n_jobs
+    ]
+    if stranded:
+        raise RuntimeError(
+            f"fluid {scenario}/{cfg.policy}: {len(stranded)} lanes reached "
+            f"the horizon cap of {cfg.max_steps} ticks "
+            f"({cfg.max_steps * cfg.dt:g} s at dt {cfg.dt:g}) with jobs "
+            f"unfinished ((seed, jobs) {stranded[:8]}); pass a larger "
+            "max_steps"
+        )
     return [
         metrics_mod.from_jcts(
             jct[i][fin[i]].tolist(),
@@ -372,6 +387,7 @@ def monte_carlo_fluid(
             n_jobs=scn.n_jobs,
             makespan=float(mks[i]),
             wall_s=wall,
+            chunks=out["chunks"],
         )
         for i, (seed, scn) in enumerate(zip(seeds, scns))
     ]

@@ -35,7 +35,7 @@ import dataclasses
 import functools
 from typing import Dict, Tuple
 
-from repro.core.cluster import ModelProfile
+from repro.core.cluster import ModelProfile, left_sum
 
 # Hardware constants of the roofline model (launch/mesh.py values; redefined
 # here because importing launch.mesh pulls in jax and the event-simulator
@@ -136,9 +136,9 @@ def model_profile_from_config(
     ``sum(layer_t_b) == t_b`` — the monolithic reading of a derived
     profile is exactly its fused-all plan."""
     layers = derive_layer_profiles(cfg, tokens)
-    size = sum(l.grad_bytes for l in layers)
-    t_f = sum(l.t_f for l in layers)
-    t_b = sum(l.t_b for l in layers)
+    size = left_sum(l.grad_bytes for l in layers)
+    t_f = left_sum(l.t_f for l in layers)
+    t_b = left_sum(l.t_b for l in layers)
     mem_mb = (size / GRAD_BYTES_PER_PARAM) * RESIDENT_BYTES_PER_PARAM / 1e6
     return ModelProfile(
         name=cfg.name,

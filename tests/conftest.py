@@ -2,28 +2,19 @@
 smoke tests and benches must see the real single CPU device; only the
 dry-run (a separate process) forces 512 devices.
 
-The jax *persistent compilation cache* is enabled for the test session
-(opt out with ``REPRO_NO_JAX_CACHE=1``): the suite's wall time is
-dominated by XLA compiles (the first fluid-simulator graph, the MoE train
-step, ...), and caching them makes every warm local rerun ~35% faster
-while cold runs (CI) are unaffected.  Correctness is keyed on the HLO
-hash, so stale entries cannot leak across code changes."""
-
-import os
+The suite's wall time is dominated by XLA compiles (the first
+fluid-simulator graph, the MoE train step, ...), so the session uses jax's
+persistent compilation cache wherever ``repro.compile_cache`` places it:
+``JAX_COMPILATION_CACHE_DIR`` if set, else ``.jax_cache/`` in the checkout.
+Entries are keyed on the HLO hash, so stale entries cannot leak across
+code changes."""
 
 import jax
 import pytest
 
-if not os.environ.get("REPRO_NO_JAX_CACHE"):
-    _cache_dir = os.environ.get(
-        "REPRO_JAX_CACHE_DIR",
-        os.path.join(os.path.expanduser("~"), ".cache", "repro_jax_compile"),
-    )
-    try:
-        jax.config.update("jax_compilation_cache_dir", _cache_dir)
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.5)
-    except Exception:  # older jax without the knobs: cold-compile as before
-        pass
+from repro.compile_cache import use_compile_cache
+
+use_compile_cache()
 
 
 @pytest.fixture(scope="session")

@@ -39,12 +39,13 @@ from repro.scenarios import (
     run_scenario_fluid,
 )
 from repro.scenarios.registry import Scenario
+from repro.scenarios.sweep import FLUID_EVENT_RATIO
 from repro.core.contention import ContentionParams
 
 DT = 0.02
 #: fluid-vs-event tolerance on aggregate metrics (gang placement makes the
 #: fluid backend pessimistic on shared-GPU scenarios)
-RATIO = 2.0
+RATIO = FLUID_EVENT_RATIO
 
 #: Tightened tolerance for the WFBP fusion cells: with k-way gating now
 #: *exact* on both backends (netmodel.kway_exact_start — the same closed

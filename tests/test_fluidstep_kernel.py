@@ -1,21 +1,23 @@
 """Parity of the fused Pallas fluid-step core against the lax reference.
 
 The reference path (``kernels/fluidstep/ref.py``) is the physics anchor —
-it is what CPU CI and every differential test run.  The Pallas kernel
-(``kernel.py``) must be indistinguishable through the ``ops.py`` dispatch:
-same dtypes, same values (integer planes exact, float planes to f32
-round-off), same ``inf`` sentinel for jobs with no overlapping in-flight
-transfer.  Interpreter mode runs the kernel body on CPU, so this guards
+it is what CPU CI and every differential test run; on a TPU the compiled
+kernel is the default (``tests/test_tpu_compile.py`` compiles it).  The
+Pallas kernel (``kernel.py``) must be indistinguishable through the
+``ops.py`` dispatch: same dtypes, same values (integer planes exact, float
+planes to f32 round-off), same ``inf`` sentinel for jobs with no
+overlapping in-flight transfer.  Interpreter mode runs the kernel body on CPU, so this guards
 the kernel math everywhere, not just on TPU runners.
 """
 
 import numpy as np
 import pytest
 
+import jax
 import jax.numpy as jnp
 
-from repro.kernels.fluidstep import fluid_step_core
-from repro.kernels.fluidstep.ops import FLUID_KERNEL_IMPLS, default_impl
+from repro.kernels.fluidstep import fluid_step_core, ops
+from repro.kernels.fluidstep.ops import default_impl, resolve_impl
 
 
 def _rand_inputs(seed, n_jobs=12, n_servers=6, n_domains=9):
@@ -117,12 +119,36 @@ class TestDispatch:
             fluid_step_core(loads, member, active, rem, bw, oversub,
                             b=7e-10, eta=3e-10, impl="cuda")
 
-    def test_default_is_ref(self, monkeypatch):
-        monkeypatch.delenv("REPRO_FLUID_KERNEL", raising=False)
+    def test_default_is_ref_off_tpu(self):
+        # the suite runs on the CPU backend: the lax reference is its own
+        assert jax.default_backend() != "tpu"
         assert default_impl() == "ref"
-        monkeypatch.setenv("REPRO_FLUID_KERNEL", "interpret")
-        assert default_impl() == "interpret"
-        assert default_impl() in FLUID_KERNEL_IMPLS
+        assert resolve_impl("") == "ref"
+        with jax.default_device(jax.devices("cpu")[0]):
+            assert default_impl() == "ref"
+
+    def test_default_is_compiled_kernel_on_tpu(self, monkeypatch):
+        monkeypatch.setattr(ops, "backend_platform", lambda: "tpu")
+        assert default_impl() == "tpu"
+        assert resolve_impl("") == "tpu"
+        # a caller naming an impl always gets that impl
+        assert resolve_impl("ref") == "ref"
+        assert resolve_impl("interpret") == "interpret"
+
+    def test_tpu_impl_raises_off_tpu(self):
+        loads, member, active, rem, bw, oversub = _rand_inputs(0)
+        with pytest.raises(ValueError, match="needs a TPU backend"):
+            fluid_step_core(loads, member, active, rem, bw, oversub,
+                            b=7e-10, eta=3e-10, impl="tpu")
+
+    def test_simulator_config_resolves_kernel(self):
+        from repro.core.jaxsim import JaxSimConfig, _policy_args
+
+        assert _policy_args(JaxSimConfig())[2].kernel == "ref"
+        named = JaxSimConfig(kernel="interpret")
+        assert _policy_args(named)[2].kernel == "interpret"
+        with pytest.raises(ValueError, match="needs a TPU backend"):
+            _policy_args(JaxSimConfig(kernel="tpu"))
 
     def test_ref_skips_overlap_unless_needed(self):
         loads, member, active, rem, bw, oversub = _rand_inputs(1)
@@ -130,3 +156,19 @@ class TestDispatch:
                               b=7e-10, eta=3e-10, need_overlap=False,
                               impl="ref")
         assert out["overlap"] is None
+
+
+class TestSimulatorPath:
+    @pytest.mark.parametrize("scenario", ["paper", "oversub_fabric"])
+    def test_kernel_in_simulator_matches_ref(self, scenario):
+        """The kernel on the simulator's main path (interpreter mode here)
+        finishes the same jobs at the same times as the reference."""
+        from repro.scenarios import monte_carlo_fluid
+
+        kw = dict(overrides=dict(n_jobs=10, min_iters=20, max_iters=60))
+        ref = monte_carlo_fluid(scenario, [0, 1], kernel="ref", **kw)
+        pal = monte_carlo_fluid(scenario, [0, 1], kernel="interpret", **kw)
+        for r, p in zip(ref, pal):
+            assert r.n_finished == p.n_finished == r.n_jobs
+            assert p.avg_jct == pytest.approx(r.avg_jct, rel=1e-5)
+            assert p.makespan == pytest.approx(r.makespan, rel=1e-5)

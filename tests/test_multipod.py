@@ -22,8 +22,9 @@ SCRIPT = textwrap.dedent(
     from repro.models.config import InputShape
     from repro.models.lm import RunFlags
 
-    from repro.launch.mesh import make_mesh_compat
-    mesh = make_mesh_compat((2, 2, 4), ("pod", "data", "model"))
+    from jax.sharding import AxisType
+    mesh = jax.make_mesh((2, 2, 4), ("pod", "data", "model"),
+                         axis_types=(AxisType.Auto,) * 3)
     cfg = get_config("llama3.2-1b", reduced=True)
     flags = RunFlags(remat="none", q_chunk=32)
     out = {}

@@ -335,6 +335,17 @@ class TestPaperOrderings:
         )
 
 
+def _run_cell_report_backend(cell):
+    """Spawn-pool worker body: the sweep's cell runner, then whether this
+    process initialized a JAX backend."""
+    from jax._src import xla_bridge
+
+    from repro.scenarios.sweep import run_cell
+
+    rec = run_cell(cell)
+    return rec.avg_jct, xla_bridge.backends_are_initialized()
+
+
 class TestSweepRunner:
     def test_matrix_shape_and_summary(self):
         records = sweep(
@@ -353,6 +364,21 @@ class TestSweepRunner:
         fanned = sweep(["smoke"], processes=2, **kw)
         assert [r.avg_jct for r in serial] == [r.avg_jct for r in fanned]
         assert [r.makespan for r in serial] == [r.makespan for r in fanned]
+
+    def test_pool_workers_start_no_jax_backend(self):
+        # the pool runs event cells only; a worker that brought up a JAX
+        # backend would contend with its parent for the chip on a TPU host
+        import multiprocessing as mp
+
+        from repro.scenarios.sweep import SweepCell
+
+        cells = [SweepCell("smoke", seed, "lwf", 1, "ada", "event")
+                 for seed in (0, 1)]
+        with mp.get_context("spawn").Pool(2) as pool:
+            out = pool.map(_run_cell_report_backend, cells)
+        serial = sweep(["smoke"], comms=("ada",), seeds=(0, 1))
+        assert [avg for avg, _ in out] == [r.avg_jct for r in serial]
+        assert not any(up for _, up in out)
 
     def test_policy_aliases(self):
         from repro.scenarios import canonical_comm
@@ -379,6 +405,25 @@ class TestMonteCarloCI:
             assert r.n_finished == len(serial) == scn.n_jobs
             assert r.avg_jct == pytest.approx(sum(serial) / len(serial))
             assert r.makespan == pytest.approx(float(out["makespan"]))
+
+    def test_capped_lane_raises(self):
+        # a lane still running at the horizon cap would average only the
+        # jobs that finished and read as a fast run
+        from repro.scenarios import monte_carlo_fluid
+
+        with pytest.raises(RuntimeError, match=r"horizon cap of 256 ticks"):
+            monte_carlo_fluid("smoke", (0, 1), comm="ada", dt=0.05,
+                              max_steps=256)
+
+    def test_fluid_cell_is_one_lane_batch(self):
+        from repro.scenarios import monte_carlo_fluid
+        from repro.scenarios.sweep import SweepCell, run_cell
+
+        rec = run_cell(SweepCell("smoke", 1, "lwf", 1, "srsf2", "fluid"))
+        (want,) = monte_carlo_fluid("smoke", [1], comm="srsf2", dt=0.05)
+        assert rec.n_finished == rec.n_jobs
+        assert (rec.avg_jct, rec.makespan, rec.placement, rec.comm) == (
+            want.avg_jct, want.makespan, want.placement, want.comm)
 
     def test_fluid_ci_preserves_paper_ordering(self):
         from repro.scenarios import sweep_ci
@@ -416,3 +461,16 @@ class TestMonteCarloCI:
         assert ci.avg_jct_std == pytest.approx((8.0 / 3) ** 0.5)
         assert ci.makespan_mean == pytest.approx(20.0)
         assert ci.finished_frac == 1.0
+
+    def test_means_are_left_folds(self):
+        # builtin sum() compensates rounding from Python 3.12 on (it gives
+        # 1.0 here); metrics fold left so results match on any interpreter
+        from repro.scenarios import ci_from_runs, from_jcts
+
+        jcts = [1e16, 1.0, -1e16]
+        rec = from_jcts(jcts, scenario="s", backend="event", placement="p",
+                        comm="c", seed=0, n_jobs=3, makespan=1.0)
+        assert rec.avg_jct == 0.0
+        recs = [dataclasses.replace(rec, seed=i, avg_jct=x)
+                for i, x in enumerate(jcts)]
+        assert ci_from_runs(recs)[0].avg_jct_mean == 0.0

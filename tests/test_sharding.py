@@ -12,21 +12,19 @@ import pytest
 
 # Pure-logic tests use a Mesh built lazily inside a subprocess-safe guard:
 # constructing an abstract mesh for spec computation doesn't need devices —
-# but jax.make_mesh does, so we use jax.sharding.AbstractMesh (via the
-# version-compat wrapper in repro.launch.mesh).
+# but jax.make_mesh does, so we use jax.sharding.AbstractMesh.
 import jax
-from jax.sharding import PartitionSpec as P
+from jax.sharding import AbstractMesh, PartitionSpec as P
 
-from repro.launch.mesh import make_abstract_mesh
 from repro.sharding.rules import ShardingStrategy, spec_for_param
 
 
 def mesh2d():
-    return make_abstract_mesh((16, 16), ("data", "model"))
+    return AbstractMesh((16, 16), ("data", "model"))
 
 
 def mesh3d():
-    return make_abstract_mesh((2, 16, 16), ("pod", "data", "model"))
+    return AbstractMesh((2, 16, 16), ("pod", "data", "model"))
 
 
 class TestSpecForParam:
@@ -103,8 +101,8 @@ MINI_DRYRUN = textwrap.dedent(
     from repro.models.config import InputShape
     from repro.models.lm import LM, RunFlags
 
-    from repro.launch.mesh import make_mesh_compat
-    mesh = make_mesh_compat((2, 4), ("data", "model"))
+    from repro.launch.mesh import make_host_mesh
+    mesh = make_host_mesh(2, 4)
     shape = InputShape("mini_train", seq_len=64, global_batch=4, kind="train")
     profile = Profile(strategy="tp", remat="none", q_chunk=32)
 
