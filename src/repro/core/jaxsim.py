@@ -722,50 +722,80 @@ def _next_pow2(n: int) -> int:
     return 1 << max(0, (n - 1).bit_length())
 
 
+#: query-wide counters of :func:`_drive_batched`, returned beside its
+#: results and kept in each fluid ``RunMetrics``
+DRIVER_COUNTERS = ("chunks", "lane_slots", "live_lane_slots", "compactions",
+                   "shapes")
+
+
 def _drive_batched(traces: Dict[str, jnp.ndarray], cfg: JaxSimConfig,
                    max_ways, gated) -> Dict[str, np.ndarray]:
     """Host driver: chunked scan segments with early exit and (optional)
     lane/job/bucket compaction.  ``cfg`` is the policy-stripped static
     key from :func:`_policy_args`.  Returns numpy result planes shaped
-    like the input batch, plus ``chunks``: the scan segments launched."""
-    arrival0 = np.asarray(traces["arrival"], np.float32)
-    n_lanes0, n_jobs0 = arrival0.shape
-    if "valid" not in traces:
-        traces = dict(traces)
-        traces["valid"] = jnp.ones((n_lanes0, n_jobs0), bool)
+    like the input batch, plus the :data:`DRIVER_COUNTERS`: ``chunks``
+    (scan segments launched), ``lane_slots`` and ``live_lane_slots``
+    (lanes at each launch, summed over launches: all of them, padding
+    included, and those not yet retired), ``compactions`` (re-gathers of
+    the batch) and ``shapes`` (distinct ``(lanes, jobs, buckets)`` shapes
+    launched).
+
+    Each host phase sits in a profiler span (``jax.profiler.
+    TraceAnnotation``, a no-op of about a microsecond when no trace is
+    recorded): ``fluid.init``; per chunk, with the stat ``chunk``,
+    ``fluid.launch``, ``fluid.sync``, ``fluid.retire`` when lanes finish,
+    and ``fluid.compact`` when finished lanes are considered for
+    compaction."""
+    span = jax.profiler.TraceAnnotation
+    with span("fluid.init"):
+        arrival0 = np.asarray(traces["arrival"], np.float32)
+        n_lanes0, n_jobs0 = arrival0.shape
+        if "valid" not in traces:
+            traces = dict(traces)
+            traces["valid"] = jnp.ones((n_lanes0, n_jobs0), bool)
+        state = _init_jit(traces, cfg)
     wfbp = "bucket_bytes" in traces and int(traces["bucket_bytes"].shape[-1]) > 1
     results = {
         "jct": np.full((n_lanes0, n_jobs0), np.inf, np.float32),
         "finished": np.zeros((n_lanes0, n_jobs0), bool),
         "makespan": np.zeros((n_lanes0,), np.float32),
-        "chunks": 0,
+        **dict.fromkeys(DRIVER_COUNTERS, 0),
     }
+    shapes = set()
     orig = np.arange(n_lanes0)  # current lane -> original row (-1 = retired)
-    state = _init_jit(traces, cfg)
 
     while True:
-        state = _chunk_jit(traces, state, cfg, max_ways, gated)
+        chunk = results["chunks"]
+        n_lanes_cur, n_jobs_cur = (int(d) for d in traces["arrival"].shape)
+        shapes.add((n_lanes_cur, n_jobs_cur,
+                    int(traces["bucket_bytes"].shape[-1]) if wfbp else 1))
+        results["lane_slots"] += n_lanes_cur
+        results["live_lane_slots"] += int((orig >= 0).sum())
+        with span("fluid.launch", chunk=chunk, lanes=n_lanes_cur,
+                  jobs=n_jobs_cur):
+            state = _chunk_jit(traces, state, cfg, max_ways, gated)
         results["chunks"] += 1
-        n_jobs_cur = int(traces["arrival"].shape[1])
-        n_done = np.asarray(state["n_done"])
-        tick = np.asarray(state["i"])
-        done = (n_done >= n_jobs_cur) | (tick >= cfg.max_steps)
-        newly = [l for l in np.nonzero(done)[0] if orig[l] >= 0]
+        with span("fluid.sync", chunk=chunk):
+            n_done = np.asarray(state["n_done"])
+            tick = np.asarray(state["i"])
+            done = (n_done >= n_jobs_cur) | (tick >= cfg.max_steps)
+            newly = [l for l in np.nonzero(done)[0] if orig[l] >= 0]
         if newly:
-            phase = np.asarray(state["phase"])
-            finish = np.asarray(state["finish"])
-            t_now = np.asarray(state["t"])
-            valid = np.asarray(traces["valid"])
-            arr = np.asarray(traces["arrival"], np.float32)
-            for l in newly:
-                row = orig[l]
-                fin = (phase[l] == DONE) & valid[l]
-                results["jct"][row, :n_jobs_cur] = finish[l] - arr[l]
-                results["finished"][row, :n_jobs_cur] = fin
-                results["makespan"][row] = (
-                    finish[l][fin].max() if fin.any() else t_now[l]
-                )
-                orig[l] = -1
+            with span("fluid.retire", chunk=chunk):
+                phase = np.asarray(state["phase"])
+                finish = np.asarray(state["finish"])
+                t_now = np.asarray(state["t"])
+                valid = np.asarray(traces["valid"])
+                arr = np.asarray(traces["arrival"], np.float32)
+                for l in newly:
+                    row = orig[l]
+                    fin = (phase[l] == DONE) & valid[l]
+                    results["jct"][row, :n_jobs_cur] = finish[l] - arr[l]
+                    results["finished"][row, :n_jobs_cur] = fin
+                    results["makespan"][row] = (
+                        finish[l][fin].max() if fin.any() else t_now[l]
+                    )
+                    orig[l] = -1
         if done.all():
             break
         if not (cfg.compact and done.any()):
@@ -778,46 +808,51 @@ def _drive_batched(traces: Dict[str, jnp.ndarray], cfg: JaxSimConfig,
         # all-invalid across the surviving lanes, so results are
         # unchanged bit-for-bit (padded jobs are inert in every
         # reduction of the step).
-        live = np.nonzero(~done)[0]
-        n_live = len(live)
-        lanes_new = _next_pow2(n_live)
-        valid = np.asarray(traces["valid"])
-        pad_lane = int(np.nonzero(done)[0][0])
-        sel = np.concatenate(
-            [live, np.full(lanes_new - n_live, pad_lane, live.dtype)]
-        )
-        col_used = valid[live].any(axis=0)
-        jobs_need = (
-            int(np.nonzero(col_used)[0][-1]) + 1 if col_used.any() else 1
-        )
-        jobs_new = min(n_jobs_cur, max(8, -(-jobs_need // 8) * 8))
-        if lanes_new >= len(done) and jobs_new > 3 * n_jobs_cur // 4:
-            continue
-        sel_dev = jnp.asarray(sel)
-        traces = {
-            k: jnp.take(v, sel_dev, axis=0)[:, :jobs_new]
-            for k, v in traces.items()
-        }
-        state = {
-            k: (
-                jnp.take(v, sel_dev, axis=0)[:, :jobs_new]
-                if v.ndim >= 2 and v.shape[1] == n_jobs_cur
-                else jnp.take(v, sel_dev, axis=0)
+        with span("fluid.compact", chunk=chunk):
+            live = np.nonzero(~done)[0]
+            n_live = len(live)
+            lanes_new = _next_pow2(n_live)
+            valid = np.asarray(traces["valid"])
+            pad_lane = int(np.nonzero(done)[0][0])
+            sel = np.concatenate(
+                [live, np.full(lanes_new - n_live, pad_lane, live.dtype)]
             )
-            for k, v in state.items()
-        }
-        state["n_done"] = (state["phase"] == DONE).sum(axis=1).astype(jnp.int32)
-        if wfbp:
-            b_cur = int(traces["bucket_bytes"].shape[-1])
-            # keep >= 2 bucket columns: collapsing to one would flip the
-            # static wfbp flag (a different gating cadence, not just a
-            # smaller graph)
-            b_need = max(2, int(np.asarray(traces["n_buckets"]).max()))
-            if b_need < b_cur:
-                traces["bucket_bytes"] = traces["bucket_bytes"][:, :, :b_need]
-        orig = np.concatenate(
-            [orig[live], np.full(lanes_new - n_live, -1, orig.dtype)]
-        )
+            col_used = valid[live].any(axis=0)
+            jobs_need = (
+                int(np.nonzero(col_used)[0][-1]) + 1 if col_used.any() else 1
+            )
+            jobs_new = min(n_jobs_cur, max(8, -(-jobs_need // 8) * 8))
+            if lanes_new >= len(done) and jobs_new > 3 * n_jobs_cur // 4:
+                continue
+            sel_dev = jnp.asarray(sel)
+            traces = {
+                k: jnp.take(v, sel_dev, axis=0)[:, :jobs_new]
+                for k, v in traces.items()
+            }
+            state = {
+                k: (
+                    jnp.take(v, sel_dev, axis=0)[:, :jobs_new]
+                    if v.ndim >= 2 and v.shape[1] == n_jobs_cur
+                    else jnp.take(v, sel_dev, axis=0)
+                )
+                for k, v in state.items()
+            }
+            state["n_done"] = (
+                (state["phase"] == DONE).sum(axis=1).astype(jnp.int32))
+            if wfbp:
+                b_cur = int(traces["bucket_bytes"].shape[-1])
+                # keep >= 2 bucket columns: collapsing to one would flip the
+                # static wfbp flag (a different gating cadence, not just a
+                # smaller graph)
+                b_need = max(2, int(np.asarray(traces["n_buckets"]).max()))
+                if b_need < b_cur:
+                    traces["bucket_bytes"] = (
+                        traces["bucket_bytes"][:, :, :b_need])
+            orig = np.concatenate(
+                [orig[live], np.full(lanes_new - n_live, -1, orig.dtype)]
+            )
+            results["compactions"] += 1
+    results["shapes"] = len(shapes)
     return results
 
 
@@ -850,10 +885,12 @@ def simulate_trace(trace: Dict[str, jnp.ndarray], cfg: JaxSimConfig):
 def simulate_traces_batched(traces: Dict[str, jnp.ndarray], cfg: JaxSimConfig):
     """Chunked-scan launches over a stacked batch of traces (leading axis
     = seed; see :func:`stack_traces`).  Returns per-lane jct/finished
-    arrays, a per-lane makespan vector and the number of scan chunks
-    launched — the scenario Monte-Carlo entry point.  Policy-dynamic like
-    :func:`simulate_trace`; finished lanes retire between chunks
-    (``cfg.compact``) so stragglers don't pay full batch width."""
+    arrays, a per-lane makespan vector and the driver's query-wide
+    counters (:data:`DRIVER_COUNTERS`: scan chunks launched, lane slots,
+    compactions, shapes) — the scenario Monte-Carlo entry point.
+    Policy-dynamic like :func:`simulate_trace`; finished lanes retire
+    between chunks (``cfg.compact``) so stragglers don't pay full batch
+    width."""
     max_ways, gated, cfg_key = _policy_args(cfg)
     out = _drive_batched(
         {k: jnp.asarray(v) for k, v in traces.items()}, cfg_key, max_ways, gated
@@ -862,7 +899,7 @@ def simulate_traces_batched(traces: Dict[str, jnp.ndarray], cfg: JaxSimConfig):
         "jct": jnp.asarray(out["jct"]),
         "finished": jnp.asarray(out["finished"]),
         "makespan": jnp.asarray(out["makespan"]),
-        "chunks": out["chunks"],
+        **{k: out[k] for k in DRIVER_COUNTERS},
     }
 
 

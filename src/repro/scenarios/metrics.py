@@ -101,6 +101,15 @@ class RunMetrics:
     #: fluid backend: scan chunks the batched driver launched for the
     #: batch this run was part of (0 = event backend)
     chunks: int = 0
+    #: fluid backend, for the same batch (0 = event backend): lanes at
+    #: each chunk's launch summed over chunks, padding included
+    #: (``lane_slots``) and of those the lanes not yet retired
+    #: (``live_lane_slots``); re-gathers of the batch (``compactions``);
+    #: distinct (lanes, jobs, buckets) shapes launched (``shapes``)
+    lane_slots: int = 0
+    live_lane_slots: int = 0
+    compactions: int = 0
+    shapes: int = 0
 
     def as_csv_row(self) -> str:
         vals = []
@@ -146,6 +155,10 @@ def from_jcts(
     stretch_frac: float = math.nan,
     gating_frac: float = math.nan,
     chunks: int = 0,
+    lane_slots: int = 0,
+    live_lane_slots: int = 0,
+    compactions: int = 0,
+    shapes: int = 0,
 ) -> RunMetrics:
     jcts = [float(x) for x in jcts]
     n_fin = len(jcts)
@@ -178,6 +191,10 @@ def from_jcts(
         stretch_frac=stretch_frac,
         gating_frac=gating_frac,
         chunks=chunks,
+        lane_slots=lane_slots,
+        live_lane_slots=live_lane_slots,
+        compactions=compactions,
+        shapes=shapes,
     )
 
 

@@ -341,56 +341,67 @@ def monte_carlo_fluid(
     (``max_steps`` ticks) with jobs unfinished: its JCT statistics would
     cover only the jobs that finished and read as a fast run."""
     import numpy as np
+    from jax.profiler import TraceAnnotation as span
 
     from repro.core.jaxsim import (
+        DRIVER_COUNTERS,
         simulate_traces_batched,
         stack_traces,
         trace_from_jobs,
     )
 
     seeds = list(seeds)
-    scns = [get_scenario(scenario, seed=s, **(overrides or {})) for s in seeds]
-    cfg = fluid_config(
-        scns[0], comm=comm, placement=placement, dt=dt,
-        max_steps=max_steps, **fast_kw,
-    )
-    t0 = time.time()
-    batch = stack_traces(
-        [trace_from_jobs(s.job_list(), fusion=s.fusion) for s in scns]
-    )
-    out = simulate_traces_batched(batch, cfg)
-    jct = np.asarray(out["jct"])
-    fin = np.asarray(out["finished"])
-    mks = np.asarray(out["makespan"])
-    wall = (time.time() - t0) / len(seeds)
-    stranded = [
-        (seed, scn.n_jobs - int(fin[i].sum()))
-        for i, (seed, scn) in enumerate(zip(seeds, scns))
-        if fin[i].sum() != scn.n_jobs
-    ]
-    if stranded:
-        raise RuntimeError(
-            f"fluid {scenario}/{cfg.policy}: {len(stranded)} lanes reached "
-            f"the horizon cap of {cfg.max_steps} ticks "
-            f"({cfg.max_steps * cfg.dt:g} s at dt {cfg.dt:g}) with jobs "
-            f"unfinished ((seed, jobs) {stranded[:8]}); pass a larger "
-            "max_steps"
-        )
-    return [
-        metrics_mod.from_jcts(
-            jct[i][fin[i]].tolist(),
-            scenario=scenario,
-            backend="fluid",
-            placement=f"gang-{cfg.placement}",
-            comm=cfg.policy,
-            seed=seed,
-            n_jobs=scn.n_jobs,
-            makespan=float(mks[i]),
-            wall_s=wall,
-            chunks=out["chunks"],
-        )
-        for i, (seed, scn) in enumerate(zip(seeds, scns))
-    ]
+    with span("fluid.query", lanes=len(seeds)):
+        with span("fluid.build"):
+            with span("fluid.build.scenario"):
+                scns = [get_scenario(scenario, seed=s, **(overrides or {}))
+                        for s in seeds]
+                cfg = fluid_config(
+                    scns[0], comm=comm, placement=placement, dt=dt,
+                    max_steps=max_steps, **fast_kw,
+                )
+            t0 = time.time()
+            with span("fluid.build.encode"):
+                traces = [trace_from_jobs(s.job_list(), fusion=s.fusion)
+                          for s in scns]
+            with span("fluid.build.stack"):
+                batch = stack_traces(traces)
+            del traces  # else every lane's arrays stay on the device
+        out = simulate_traces_batched(batch, cfg)
+        with span("fluid.collect"):
+            jct = np.asarray(out["jct"])
+            fin = np.asarray(out["finished"])
+            mks = np.asarray(out["makespan"])
+            wall = (time.time() - t0) / len(seeds)
+            stranded = [
+                (seed, scn.n_jobs - int(fin[i].sum()))
+                for i, (seed, scn) in enumerate(zip(seeds, scns))
+                if fin[i].sum() != scn.n_jobs
+            ]
+            if stranded:
+                raise RuntimeError(
+                    f"fluid {scenario}/{cfg.policy}: {len(stranded)} lanes "
+                    f"reached the horizon cap of {cfg.max_steps} ticks "
+                    f"({cfg.max_steps * cfg.dt:g} s at dt {cfg.dt:g}) with "
+                    f"jobs unfinished ((seed, jobs) {stranded[:8]}); pass a "
+                    "larger max_steps"
+                )
+            counters = {k: out[k] for k in DRIVER_COUNTERS}
+            return [
+                metrics_mod.from_jcts(
+                    jct[i][fin[i]].tolist(),
+                    scenario=scenario,
+                    backend="fluid",
+                    placement=f"gang-{cfg.placement}",
+                    comm=cfg.policy,
+                    seed=seed,
+                    n_jobs=scn.n_jobs,
+                    makespan=float(mks[i]),
+                    wall_s=wall,
+                    **counters,
+                )
+                for i, (seed, scn) in enumerate(zip(seeds, scns))
+            ]
 
 
 def sweep_ci(
