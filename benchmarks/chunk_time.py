@@ -1,21 +1,20 @@
 """Time of one fluid scan chunk (``jaxsim._chunk_jit``, ``chunk_steps``
-ticks) per step-kernel implementation and lane count.
+ticks) per lane count.
 
 The ``paper`` scenario at full size (160 jobs per lane) is stacked into
 a batch; each line is the first call (compile included) and the warm
 mean over ``--reps`` further chunks, timed on the host clock around
-``block_until_ready``.  ``--cpu-lanes`` adds a ``ref`` row on the host's
-CPU device.  These are per-layer numbers for the chunk-scan layer, not
+``block_until_ready``.  ``--cpu-lanes`` adds a row on the host's CPU
+device.  These are per-layer numbers for the chunk-scan layer, not
 end-to-end metrics.
 
-Usage (from the root of the checkout; ``tpu`` needs a TPU):
-    python3 benchmarks/chunk_time.py --kernels ref tpu --lanes 8 128 1024
+Usage (from the root of the checkout):
+    python3 benchmarks/chunk_time.py --lanes 8 128 1024
 """
 
 from __future__ import annotations
 
 import argparse
-import dataclasses
 import os
 import sys
 import time
@@ -27,10 +26,9 @@ sys.path.insert(0, str(Path(__file__).resolve().parents[1] / "src"))
 def main() -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--scenario", default="paper")
-    ap.add_argument("--kernels", nargs="+", default=["ref", "tpu"])
     ap.add_argument("--lanes", type=int, nargs="+", default=[8, 128, 1024])
     ap.add_argument("--cpu-lanes", type=int, default=4,
-                    help="lanes of the host-CPU ref row (0: none)")
+                    help="lanes of the host-CPU row (0: none)")
     ap.add_argument("--reps", type=int, default=3)
     args = ap.parse_args()
 
@@ -55,8 +53,8 @@ def main() -> int:
                           for s in scns])
     base = fluid_config(scns[0])
 
-    def per_chunk(kernel: str, lanes: int, device) -> None:
-        max_ways, gated, key = _policy_args(dataclasses.replace(base, kernel=kernel))
+    def per_chunk(lanes: int, device) -> None:
+        max_ways, gated, key = _policy_args(base)
         tr, max_ways, gated = jax.device_put(
             ({k: v[:lanes] for k, v in batch.items()}, max_ways, gated), device)
         with jax.default_device(device):
@@ -69,15 +67,14 @@ def main() -> int:
                 st = _chunk_jit(tr, st, key, max_ways, gated)
             jax.block_until_ready(st)
         warm = (time.perf_counter() - t0) / args.reps
-        print(f"{device.platform} kernel={kernel} lanes {lanes}: first call "
+        print(f"{device.platform} lanes {lanes}: first call "
               f"{first!r} s, warm {warm * 1e3!r} ms per {base.chunk_steps}-tick "
               "chunk", flush=True)
 
-    for kernel in args.kernels:
-        for lanes in args.lanes:
-            per_chunk(kernel, lanes, dev)
+    for lanes in args.lanes:
+        per_chunk(lanes, dev)
     if args.cpu_lanes:
-        per_chunk("ref", args.cpu_lanes, jax.devices("cpu")[0])
+        per_chunk(args.cpu_lanes, jax.devices("cpu")[0])
     return 0
 
 

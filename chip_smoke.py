@@ -3,16 +3,14 @@
 
 The paper's cluster (16 servers x 4 GPUs, 160 jobs per lane, Ada-SRSF
 gating, LWF placement) is simulated for ``LANES`` seeded lanes through
-``repro.scenarios.monte_carlo_fluid``: once with the step kernel the chip
-picks by default (the compiled Pallas kernel) and once with the lax
-reference.  Then:
+``repro.scenarios.monte_carlo_fluid`` on the chip, at the published
+trace's iterations per job.  Then:
 
 (a) every job of every lane finished inside the horizon;
-(b) on the chip, kernel and reference finish the same jobs, and each
-    lane's average JCT agrees within ``JCT_REL_TOL``;
-(c) ``CPU_LANES`` lanes rerun on the host's CPU device agree with the
-    chip's kernel lanes the same way;
-(d) the event engine on ``EVENT_SEEDS`` seeds agrees with the fluid
+(b) ``CPU_LANES`` lanes rerun on the host's CPU device finish the same
+    jobs as the chip's, and each lane's average JCT agrees within
+    ``JCT_REL_TOL``;
+(c) the event engine on ``EVENT_SEEDS`` seeds agrees with the fluid
     average JCT within ``FLUID_EVENT_RATIO``.
 
 Timings, rollouts per second and chunk counts are printed for
@@ -39,14 +37,9 @@ SCENARIO, COMM, PLACEMENT = "paper", "ada", "lwf"
 LANES = 1024
 #: the published trace's iterations per job (paper Section V-A)
 PUBLISHED_ITERS = (1000, 6000)
-#: cut to a twelfth, the cluster and job count kept: on one v5e the
-#: compiled kernel takes about 1 s per 256-tick chunk at 1024 lanes, and
-#: the published range needs about 1,600 chunks per call, so the script
-#: would run for most of an hour instead of a few minutes
-ITERS = (83, 500)
 DT = 0.05
 #: bound on |avg JCT difference| / avg JCT per lane between two float32
-#: evaluations of the same rollout (kernel vs reference, chip vs CPU)
+#: evaluations of the same rollout (chip vs host CPU)
 JCT_REL_TOL = 0.02
 CPU_LANES = 4
 EVENT_SEEDS = 2
@@ -73,7 +66,7 @@ def check_all_finished(label: str, recs, n_jobs: int) -> None:
 
 
 def check_parity(label: str, got, want) -> None:
-    """Checks (b)/(c): same finished jobs (all of them, after (a)) and
+    """Check (b): same finished jobs (all of them, after (a)) and
     per-lane average JCT within JCT_REL_TOL."""
     worst, worst_seed = 0.0, None
     for g, w in zip(got, want, strict=True):
@@ -91,7 +84,7 @@ def check_parity(label: str, got, want) -> None:
         fail(f"{label}: avg JCT differs by {worst!r} > {JCT_REL_TOL}")
 
 
-def run_fluid(label: str, seeds, overrides, *, warm: bool, **kw):
+def run_fluid(label: str, seeds, overrides, *, warm: bool):
     """Run the Monte-Carlo entry point (twice when ``warm``); print the
     informational times.  Returns the records of the last call."""
     from repro.scenarios import monte_carlo_fluid
@@ -100,7 +93,7 @@ def run_fluid(label: str, seeds, overrides, *, warm: bool, **kw):
         t0 = time.perf_counter()
         recs = monte_carlo_fluid(
             SCENARIO, seeds, comm=COMM, placement=PLACEMENT,
-            overrides=overrides, dt=DT, **kw,
+            overrides=overrides, dt=DT,
         )
         return recs, time.perf_counter() - t0
 
@@ -119,11 +112,11 @@ def main() -> int:
     t_start = time.perf_counter()
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--lanes", type=int, default=LANES)
-    ap.add_argument("--iters", type=int, nargs=2, default=ITERS,
+    ap.add_argument("--iters", type=int, nargs=2, default=PUBLISHED_ITERS,
                     metavar=("MIN", "MAX"))
     args = ap.parse_args()
 
-    # check (c) needs the host's CPU device beside the chip
+    # check (b) needs the host's CPU device beside the chip
     plats = os.environ.get("JAX_PLATFORMS", "")
     if plats and "cpu" not in plats.split(","):
         os.environ["JAX_PLATFORMS"] = plats + ",cpu"
@@ -139,7 +132,6 @@ def main() -> int:
 
     from repro.compile_cache import use_compile_cache
     from repro.core.jaxsim import JaxSimConfig
-    from repro.kernels.fluidstep import resolve_impl
     from repro.scenarios import get_scenario, run_scenario_event
     from repro.scenarios.sweep import FLUID_EVENT_RATIO
 
@@ -155,28 +147,17 @@ def main() -> int:
           f"({cut}), dt {DT} s, horizon {JaxSimConfig.max_steps * DT:.0f} s",
           flush=True)
 
-    impl = resolve_impl("")
-    if impl != "tpu":
-        fail(f"the chip's default step kernel is {impl!r}, not 'tpu'")
-    chip = run_fluid("chip kernel=tpu (default)", seeds, overrides, warm=True)
+    chip = run_fluid("chip", seeds, overrides, warm=True)
     stats = dev.memory_stats() or {}
     print(f"memory: peak_bytes_in_use {stats.get('peak_bytes_in_use')} "
           f"of bytes_limit {stats.get('bytes_limit')}", flush=True)
-    check_all_finished("chip kernel=tpu", chip, n_jobs)
-
-    ref = run_fluid("chip kernel=ref", seeds, overrides, warm=True,
-                    kernel="ref")
-    check_all_finished("chip kernel=ref", ref, n_jobs)
-    check_parity("(b) chip tpu vs chip ref", chip, ref)
+    check_all_finished("chip", chip, n_jobs)
 
     cpu_seeds = seeds[:CPU_LANES]
     with jax.default_device(jax.devices("cpu")[0]):
-        if resolve_impl("") != "ref":
-            fail("the host CPU's default step kernel is not 'ref'")
-        cpu = run_fluid("host cpu kernel=ref (default)", cpu_seeds,
-                        overrides, warm=False)
+        cpu = run_fluid("host cpu", cpu_seeds, overrides, warm=False)
     check_all_finished("host cpu", cpu, n_jobs)
-    check_parity("(c) host cpu vs chip tpu", cpu, chip[:CPU_LANES])
+    check_parity("(b) host cpu vs chip", cpu, chip[:CPU_LANES])
 
     for seed in range(EVENT_SEEDS):
         t0 = time.perf_counter()
@@ -185,14 +166,14 @@ def main() -> int:
         ev_avg, fl_avg = ev.avg_jct(), chip[seed].avg_jct
         print(f"event engine seed {seed}: {time.perf_counter() - t0!r} s, "
               f"{len(ev.jct)} jobs finished, avg JCT {ev_avg!r} s; "
-              f"fluid (chip kernel) {fl_avg!r} s, ratio {fl_avg / ev_avg!r}",
+              f"fluid (chip) {fl_avg!r} s, ratio {fl_avg / ev_avg!r}",
               flush=True)
         if len(ev.jct) != n_jobs:
             fail(f"event engine seed {seed} finished {len(ev.jct)} jobs")
         if not ev_avg / FLUID_EVENT_RATIO <= fl_avg <= ev_avg * FLUID_EVENT_RATIO:
-            fail(f"(d) seed {seed}: fluid {fl_avg} vs event {ev_avg} outside "
+            fail(f"(c) seed {seed}: fluid {fl_avg} vs event {ev_avg} outside "
                  f"x{FLUID_EVENT_RATIO}")
-    print(f"check (d) fluid vs event: {EVENT_SEEDS} seeds within "
+    print(f"check (c) fluid vs event: {EVENT_SEEDS} seeds within "
           f"x{FLUID_EVENT_RATIO}")
     print(f"total wall {time.perf_counter() - t_start!r} s")
 

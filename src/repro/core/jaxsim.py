@@ -60,8 +60,7 @@ ticks.  It is a *segmented* driver:
 * **Fused step core** — the per-tick contention/rate evaluation (domain
   incidence matmuls, Eq. 5 rate, slowest-member scale, gating-side
   ``k_would``/``min_old_rem``) is one call into
-  ``repro.kernels.fluidstep``: the compiled Pallas kernel on a TPU, the
-  lax reference elsewhere (``cfg.kernel`` names one explicitly).
+  ``repro.kernels.fluidstep``, the same lax code on every backend.
 
 Remaining approximations vs the event simulator (``core/simulator.py``),
 all documented and tested for *qualitative* agreement:
@@ -157,9 +156,6 @@ class JaxSimConfig:
     skip: bool = True
     #: retire finished lanes / trim padding between chunks.
     compact: bool = True
-    #: fluid step core impl: "ref" | "interpret" | "tpu"; "" = "tpu" when
-    #: the default device is a TPU, else "ref" (resolved per launch).
-    kernel: str = ""
 
     def __post_init__(self) -> None:
         if self.gating not in ("fixedpoint", "rounds"):
@@ -241,17 +237,13 @@ _EXACT_KWAY_POLICY = "<exact-kway>"
 def _policy_args(cfg: JaxSimConfig):
     """(max_ways, threshold_gated) as arrays + the policy-stripped static
     config key; threshold policies (ada/srsfN) all share one compiled
-    graph, exact-lookahead ``kwayK`` policies share another.  The key
-    carries the resolved step-core impl, so a launch under another
-    default device never reuses a graph built for a different kernel."""
+    graph, exact-lookahead ``kwayK`` policies share another."""
     spec = netmodel.parse_policy(cfg.policy)
     sentinel = _EXACT_KWAY_POLICY if spec.exact_lookahead else _DYNAMIC_POLICY
     return (
         jnp.asarray(spec.max_ways, jnp.float32),
         jnp.asarray(spec.threshold_gated, bool),
-        dataclasses.replace(
-            cfg, policy=sentinel, kernel=fluidstep.resolve_impl(cfg.kernel)
-        ),
+        dataclasses.replace(cfg, policy=sentinel),
     )
 
 
@@ -438,8 +430,7 @@ def _make_lane_step(trace: Dict[str, jnp.ndarray], cfg: JaxSimConfig,
         # ONE fused evaluation of the contention/rate core: in-flight
         # counts over the carried domain-load mask, oversub-weighted
         # effective k, Eq. 5 drain ratio, and the gating-side k_would /
-        # min_old_rem (+ the overlap matrix where gating needs it).
-        # Dispatches to the lax reference or the Pallas kernel
+        # min_old_rem (+ the overlap matrix where gating needs it)
         # (repro.kernels.fluidstep).  Evaluated pre-compute-drain:
         # min_old_rem/k_would only read COMM rows, whose ``rem`` the
         # compute drain below cannot touch — bit-exact with the legacy
@@ -447,7 +438,7 @@ def _make_lane_step(trace: Dict[str, jnp.ndarray], cfg: JaxSimConfig,
         core = fluidstep.fluid_step_core(
             loads, member, active, rem, bw, oversub,
             b=cfg.b, eta=cfg.eta,
-            need_overlap=(wfbp or exact_kway), impl=cfg.kernel,
+            need_overlap=(wfbp or exact_kway),
         )
         counts = core["counts"]
         k_eff, overlap = core["k_eff"], core["overlap"]

@@ -1,9 +1,8 @@
-"""Reference (pure-lax) fluid step core.
+"""The fluid step core: one tick's contention/rate evaluation, in lax.
 
 This is the contention/rate op sequence the fluid simulator's hot loop
-historically inlined per tick (core/jaxsim.py pre-fast-path), factored
-out so the Pallas kernel (kernel.py) has a bit-for-bit target to verify
-against and the simulator has a single call site for the math:
+runs once per executed tick (``core/jaxsim.py``), factored out so the
+simulator has a single call site for the math:
 
 * per-domain in-flight counts over the (precomputed) domain-load mask,
 * the Eq. 5 contended rate at the oversub-weighted effective k,
@@ -26,9 +25,10 @@ is O(J·D) with no J×J intermediate.  The overlap matrix itself is only
 materialized when ``need_overlap`` (WFBP gating closure / exact k-way
 lookahead paths).
 
-This path is the default on every backend but the TPU (CPU CI, all
-differential tests), so the fast-path refactor cannot drift the physics:
-the kernel is an accelerator, not a second source of truth.
+The same code runs on every backend.  Under the Monte-Carlo driver's
+``vmap`` XLA is free to lay each lane-batched array out with the lane
+axis minor, which on a TPU keeps the whole chunk scan in dense
+128-lane tiles; no custom call pins a layout.
 """
 
 from __future__ import annotations
@@ -38,8 +38,8 @@ import jax.numpy as jnp
 from repro.core import netmodel
 
 
-def fluid_step_core_ref(loads, member, active, rem, bw, oversub, *,
-                        b: float, eta: float, need_overlap: bool = False):
+def fluid_step_core(loads, member, active, rem, bw, oversub, *,
+                    b: float, eta: float, need_overlap: bool = False):
     """One evaluation of the contention/rate core.
 
     Args:
@@ -68,15 +68,18 @@ def fluid_step_core_ref(loads, member, active, rem, bw, oversub, *,
     # per-domain minimum in-flight remainder, then min over loaded domains
     dmin = jnp.where(loads & active[:, None], rem[:, None], jnp.inf).min(axis=0)
     min_old_rem = jnp.where(loads, dmin[None, :], jnp.inf).min(axis=1)
-    overlap = None
-    if need_overlap:
-        loads_f = loads.astype(jnp.float32)
-        overlap = (loads_f @ loads_f.T) > 0  # (J, J) share a domain
     return {
         "counts": counts,
         "k_eff": k_eff,
         "ratio": ratio,
         "k_would": k_would,
         "min_old_rem": min_old_rem,
-        "overlap": overlap,
+        "overlap": job_overlap(loads) if need_overlap else None,
     }
+
+
+def job_overlap(loads):
+    """``(J, J)`` bool: which jobs load a common domain (an exact product
+    of {0,1} values compared with 0)."""
+    loads_f = loads.astype(jnp.float32)
+    return (loads_f @ loads_f.T) > 0

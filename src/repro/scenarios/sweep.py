@@ -116,7 +116,7 @@ def fluid_config(
     contention); event placement names map to their gang analogues
     (lwf->consolidate, ff->first_fit, ls->least_loaded, rand->random,
     lwf_rack->rack_pack).  ``fast_kw`` forwards the fast-path knobs
-    (``skip``, ``gating``, ``compact``, ``chunk_steps``, ``kernel``) —
+    (``skip``, ``gating``, ``compact``, ``chunk_steps``) —
     how the equivalence tests pin e.g. ``gating="rounds", skip=False``.
     ``max_steps=None`` keeps the config's horizon cap."""
     from repro.core.jaxsim import JaxSimConfig

@@ -1,13 +1,18 @@
-"""Parity of the fused Pallas fluid-step core against the lax reference.
+"""The fluid step core (``kernels/fluidstep/core.py``) against a plain
+NumPy evaluation of the same definitions, and under ``vmap``.
 
-The reference path (``kernels/fluidstep/ref.py``) is the physics anchor —
-it is what CPU CI and every differential test run; on a TPU the compiled
-kernel is the default (``tests/test_tpu_compile.py`` compiles it).  The
-Pallas kernel (``kernel.py``) must be indistinguishable through the
-``ops.py`` dispatch: same dtypes, same values (integer planes exact, float
-planes to f32 round-off), same ``inf`` sentinel for jobs with no
-overlapping in-flight transfer.  Interpreter mode runs the kernel body on CPU, so this guards
-the kernel math everywhere, not just on TPU runners.
+The plain evaluation walks jobs and domains one by one, straight from
+the definitions: in-flight counts per domain, the oversub-weighted
+effective k and the gating-side k of a new start (max over the domains a
+job loads, at least 1), the slowest member server's bandwidth, the Eq. 5
+rate fraction, Theorem 2's M_old (the least remainder of an in-flight job
+sharing a domain, ``inf`` where none does) and the job overlap matrix.
+Integer planes, masks and M_old must match exactly; the float rates to
+float32 round-off.
+
+Under the Monte-Carlo driver the core runs inside ``vmap`` (and inside
+the chunk's ``lax.scan``); batched it must give the bits it gives lane by
+lane.
 """
 
 import numpy as np
@@ -16,8 +21,9 @@ import pytest
 import jax
 import jax.numpy as jnp
 
-from repro.kernels.fluidstep import fluid_step_core, ops
-from repro.kernels.fluidstep.ops import default_impl, resolve_impl
+from repro.kernels.fluidstep import fluid_step_core
+
+B, ETA = 7e-10, 3e-10
 
 
 def _rand_inputs(seed, n_jobs=12, n_servers=6, n_domains=9):
@@ -39,136 +45,160 @@ def _rand_inputs(seed, n_jobs=12, n_servers=6, n_domains=9):
     )
 
 
-def _both(seed, **kw):
-    loads, member, active, rem, bw, oversub = _rand_inputs(seed, **kw)
-    args = dict(b=7e-10, eta=3e-10, need_overlap=True)
-    ref = fluid_step_core(loads, member, active, rem, bw, oversub,
-                          impl="ref", **args)
-    pal = fluid_step_core(loads, member, active, rem, bw, oversub,
-                          impl="interpret", **args)
-    return ref, pal
+def _plain(loads, member, active, rem, bw, oversub, b=B, eta=ETA):
+    """The step core's outputs, job by job, in NumPy float32."""
+    loads, member, active = (np.asarray(x) for x in (loads, member, active))
+    rem, bw, oversub = (np.asarray(x, np.float32) for x in (rem, bw, oversub))
+    n_jobs, n_domains = loads.shape
+    f32 = np.float32
+    counts = np.array([sum(bool(loads[j, d] and active[j])
+                           for j in range(n_jobs))
+                       for d in range(n_domains)], np.int32)
+    k_eff = np.ones(n_jobs, f32)
+    k_would = np.ones(n_jobs, np.int32)
+    ratio = np.empty(n_jobs, f32)
+    min_old = np.full(n_jobs, np.inf, f32)
+    overlap = np.zeros((n_jobs, n_jobs), bool)
+    for i in range(n_jobs):
+        doms = [d for d in range(n_domains) if loads[i, d]]
+        for d in doms:
+            k_eff[i] = max(k_eff[i], f32(counts[d]) * oversub[d])
+            k_would[i] = max(k_would[i], counts[d] + 1)
+        servers = [s for s in range(member.shape[1]) if member[i, s] > 0]
+        scale = min(bw[s] for s in servers) if servers else f32(1.0)
+        k = k_eff[i]
+        ratio[i] = scale * (f32(b) / (k * f32(b) + (k - f32(1)) * f32(eta)))
+        for j in range(n_jobs):
+            overlap[i, j] = any(loads[j, d] for d in doms)
+            if overlap[i, j] and active[j]:
+                min_old[i] = min(min_old[i], rem[j])
+    return {"counts": counts, "k_eff": k_eff, "ratio": ratio,
+            "k_would": k_would, "min_old_rem": min_old, "overlap": overlap}
 
 
-class TestPallasParity:
+def _assert_plain(out, want):
+    for key in ("counts", "k_would", "k_eff", "min_old_rem", "overlap"):
+        np.testing.assert_array_equal(np.asarray(out[key]), want[key],
+                                      err_msg=key)
+    np.testing.assert_allclose(np.asarray(out["ratio"]), want["ratio"],
+                               rtol=1e-6)
+
+
+class TestPlainParity:
+    @pytest.mark.parametrize("widths", [(12, 6, 9), (40, 8, 20), (160, 16, 16)])
     @pytest.mark.parametrize("seed", [0, 1, 2, 3])
-    def test_random_states_match(self, seed):
-        ref, pal = _both(seed)
-        np.testing.assert_array_equal(
-            np.asarray(ref["counts"]), np.asarray(pal["counts"])
-        )
-        np.testing.assert_array_equal(
-            np.asarray(ref["k_would"]), np.asarray(pal["k_would"])
-        )
-        np.testing.assert_array_equal(
-            np.asarray(ref["overlap"]), np.asarray(pal["overlap"])
-        )
-        np.testing.assert_allclose(
-            np.asarray(ref["k_eff"]), np.asarray(pal["k_eff"]), rtol=1e-6
-        )
-        np.testing.assert_allclose(
-            np.asarray(ref["ratio"]), np.asarray(pal["ratio"]), rtol=1e-6
-        )
-        r_min = np.asarray(ref["min_old_rem"])
-        p_min = np.asarray(pal["min_old_rem"])
-        np.testing.assert_array_equal(np.isinf(r_min), np.isinf(p_min))
-        finite = ~np.isinf(r_min)
-        np.testing.assert_allclose(r_min[finite], p_min[finite], rtol=1e-6)
+    def test_random_states_match(self, seed, widths):
+        n_jobs, n_servers, n_domains = widths
+        args = _rand_inputs(seed, n_jobs, n_servers, n_domains)
+        out = fluid_step_core(*args, b=B, eta=ETA, need_overlap=True)
+        _assert_plain(out, _plain(*args))
 
-    def test_dtypes_identical_across_impls(self):
-        ref, pal = _both(0)
-        for key in ("counts", "k_eff", "ratio", "k_would", "min_old_rem",
-                    "overlap"):
-            assert np.asarray(ref[key]).dtype == np.asarray(pal[key]).dtype, key
+    def test_output_dtypes(self):
+        out = fluid_step_core(*_rand_inputs(0), b=B, eta=ETA,
+                              need_overlap=True)
+        want = {"counts": np.int32, "k_would": np.int32,
+                "k_eff": np.float32, "ratio": np.float32,
+                "min_old_rem": np.float32, "overlap": np.bool_}
+        assert {k: np.asarray(v).dtype for k, v in out.items()} == want
 
     def test_no_active_transfers(self):
         loads, member, _, rem, bw, oversub = _rand_inputs(5)
         active = jnp.zeros(loads.shape[0], dtype=bool)
-        args = dict(b=7e-10, eta=3e-10, need_overlap=True)
-        ref = fluid_step_core(loads, member, active, rem, bw, oversub,
-                              impl="ref", **args)
-        pal = fluid_step_core(loads, member, active, rem, bw, oversub,
-                              impl="interpret", **args)
-        assert int(np.asarray(ref["counts"]).sum()) == 0
-        np.testing.assert_array_equal(
-            np.asarray(ref["counts"]), np.asarray(pal["counts"])
-        )
-        # nothing in flight -> every job's M_old is the +inf sentinel
-        assert np.isinf(np.asarray(pal["min_old_rem"])).all()
+        out = fluid_step_core(loads, member, active, rem, bw, oversub,
+                              b=B, eta=ETA, need_overlap=True)
+        assert int(np.asarray(out["counts"]).sum()) == 0
+        # nothing in flight: k is 1, the full rate, M_old the +inf sentinel
+        assert (np.asarray(out["k_eff"]) == 1.0).all()
+        assert np.isinf(np.asarray(out["min_old_rem"])).all()
+        _assert_plain(out, _plain(loads, member, active, rem, bw, oversub))
 
     def test_empty_loads_rows(self):
         loads, member, active, rem, bw, oversub = _rand_inputs(6)
         loads = loads.at[0].set(False)  # comm-less job
-        args = dict(b=7e-10, eta=3e-10, need_overlap=True)
-        ref = fluid_step_core(loads, member, active, rem, bw, oversub,
-                              impl="ref", **args)
-        pal = fluid_step_core(loads, member, active, rem, bw, oversub,
-                              impl="interpret", **args)
+        out = fluid_step_core(loads, member, active, rem, bw, oversub,
+                              b=B, eta=ETA, need_overlap=True)
         # a loadless row contends with nothing: k floors at 1, M_old = inf
-        assert float(np.asarray(ref["k_eff"])[0]) == 1.0
-        assert float(np.asarray(pal["k_eff"])[0]) == 1.0
-        assert np.isinf(np.asarray(pal["min_old_rem"])[0])
-        np.testing.assert_array_equal(
-            np.asarray(ref["overlap"]), np.asarray(pal["overlap"])
-        )
-
-
-class TestDispatch:
-    def test_unknown_impl_raises(self):
-        loads, member, active, rem, bw, oversub = _rand_inputs(0)
-        with pytest.raises(ValueError, match="unknown fluid step impl"):
-            fluid_step_core(loads, member, active, rem, bw, oversub,
-                            b=7e-10, eta=3e-10, impl="cuda")
-
-    def test_default_is_ref_off_tpu(self):
-        # the suite runs on the CPU backend: the lax reference is its own
-        assert jax.default_backend() != "tpu"
-        assert default_impl() == "ref"
-        assert resolve_impl("") == "ref"
-        with jax.default_device(jax.devices("cpu")[0]):
-            assert default_impl() == "ref"
-
-    def test_default_is_compiled_kernel_on_tpu(self, monkeypatch):
-        monkeypatch.setattr(ops, "backend_platform", lambda: "tpu")
-        assert default_impl() == "tpu"
-        assert resolve_impl("") == "tpu"
-        # a caller naming an impl always gets that impl
-        assert resolve_impl("ref") == "ref"
-        assert resolve_impl("interpret") == "interpret"
-
-    def test_tpu_impl_raises_off_tpu(self):
-        loads, member, active, rem, bw, oversub = _rand_inputs(0)
-        with pytest.raises(ValueError, match="needs a TPU backend"):
-            fluid_step_core(loads, member, active, rem, bw, oversub,
-                            b=7e-10, eta=3e-10, impl="tpu")
-
-    def test_simulator_config_resolves_kernel(self):
-        from repro.core.jaxsim import JaxSimConfig, _policy_args
-
-        assert _policy_args(JaxSimConfig())[2].kernel == "ref"
-        named = JaxSimConfig(kernel="interpret")
-        assert _policy_args(named)[2].kernel == "interpret"
-        with pytest.raises(ValueError, match="needs a TPU backend"):
-            _policy_args(JaxSimConfig(kernel="tpu"))
+        assert float(np.asarray(out["k_eff"])[0]) == 1.0
+        assert int(np.asarray(out["k_would"])[0]) == 1
+        assert np.isinf(np.asarray(out["min_old_rem"])[0])
+        assert not np.asarray(out["overlap"])[0].any()
+        _assert_plain(out, _plain(loads, member, active, rem, bw, oversub))
 
     def test_ref_skips_overlap_unless_needed(self):
-        loads, member, active, rem, bw, oversub = _rand_inputs(1)
-        out = fluid_step_core(loads, member, active, rem, bw, oversub,
-                              b=7e-10, eta=3e-10, need_overlap=False,
-                              impl="ref")
+        out = fluid_step_core(*_rand_inputs(1), b=B, eta=ETA,
+                              need_overlap=False)
         assert out["overlap"] is None
 
 
-class TestSimulatorPath:
-    @pytest.mark.parametrize("scenario", ["paper", "oversub_fabric"])
-    def test_kernel_in_simulator_matches_ref(self, scenario):
-        """The kernel on the simulator's main path (interpreter mode here)
-        finishes the same jobs at the same times as the reference."""
-        from repro.scenarios import monte_carlo_fluid
+def _lane_batch(seed, lanes, n_jobs, n_servers, n_domains):
+    """``lanes`` random states of one cluster: the per-lane planes
+    stacked lanes first; ``bw`` and ``oversub`` shared, as in the
+    simulator."""
+    per_lane = [_rand_inputs(seed + i, n_jobs, n_servers, n_domains)
+                for i in range(lanes)]
+    stacked = [jnp.stack(planes) for planes in zip(*per_lane)]
+    return (*stacked[:4], per_lane[0][4], per_lane[0][5])
 
-        kw = dict(overrides=dict(n_jobs=10, min_iters=20, max_iters=60))
-        ref = monte_carlo_fluid(scenario, [0, 1], kernel="ref", **kw)
-        pal = monte_carlo_fluid(scenario, [0, 1], kernel="interpret", **kw)
-        for r, p in zip(ref, pal):
-            assert r.n_finished == p.n_finished == r.n_jobs
-            assert p.avg_jct == pytest.approx(r.avg_jct, rel=1e-5)
-            assert p.makespan == pytest.approx(r.makespan, rel=1e-5)
+
+def _assert_same(got, want):
+    assert got.keys() == want.keys()
+    for key in got:
+        if want[key] is None:
+            assert got[key] is None, key
+            continue
+        g, w = np.asarray(got[key]), np.asarray(want[key])
+        assert g.dtype == w.dtype, key
+        np.testing.assert_array_equal(g, w, err_msg=key)
+
+
+class TestLaneBatched:
+    """The core under ``vmap``, as the chunk scan runs it: bit-identical
+    to the same core run lane by lane."""
+
+    @pytest.mark.parametrize("need_overlap", [False, True])
+    @pytest.mark.parametrize("n_domains", [16, 20])
+    @pytest.mark.parametrize("lanes", [1, 3, 37, 130])
+    def test_vmap_matches_lane_by_lane(self, lanes, n_domains, need_overlap):
+        # paper-wide jobs and servers
+        loads, member, active, rem, bw, oversub = _lane_batch(
+            lanes, lanes, n_jobs=160, n_servers=16, n_domains=n_domains)
+
+        def lane(l, m, a, r):
+            return fluid_step_core(l, m, a, r, bw, oversub, b=B, eta=ETA,
+                                   need_overlap=need_overlap)
+
+        batched = jax.jit(jax.vmap(lane))(loads, member, active, rem)
+        one = jax.jit(lane)
+        per_lane = [one(loads[i], member[i], active[i], rem[i])
+                    for i in range(lanes)]
+        want = {k: None if per_lane[0][k] is None else
+                jnp.stack([p[k] for p in per_lane]) for k in per_lane[0]}
+        _assert_same(batched, want)
+
+    def test_vmap_inside_scan(self):
+        """The per-lane core inside ``lax.scan`` inside ``vmap``,
+        remainders drained by each tick's rates so that transfers end
+        mid-scan, against the same scan run lane by lane."""
+        loads, member, active, rem, bw, oversub = _lane_batch(
+            11, 5, n_jobs=24, n_servers=6, n_domains=9)
+
+        def lane_chunk(loads, member, active, rem):
+            def tick(rem, _):
+                out = fluid_step_core(
+                    loads, member, active & (rem > 0), rem, bw, oversub,
+                    b=B, eta=ETA)
+                return jnp.maximum(rem - 3.0 * out["ratio"], 0.0), out
+            return jax.lax.scan(tick, rem, None, length=6)
+
+        rem_b, out_b = jax.jit(jax.vmap(lane_chunk))(loads, member, active,
+                                                      rem)
+        one = jax.jit(lane_chunk)
+        for i in range(loads.shape[0]):
+            rem_i, out_i = one(loads[i], member[i], active[i], rem[i])
+            np.testing.assert_array_equal(np.asarray(rem_b[i]),
+                                          np.asarray(rem_i))
+            _assert_same({k: None if v is None else v[i]
+                          for k, v in out_b.items()}, out_i)
+        # transfers really ended inside the scan
+        assert (np.asarray(rem_b) == 0).any()
+

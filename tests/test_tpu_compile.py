@@ -1,8 +1,8 @@
 """The fluid simulator's TPU programs compile for a described TPU v5e.
 
 No chip is attached: the TPU compiler compiles for a v5e that
-``jax.experimental.topologies`` describes, which catches what interpreter
-mode cannot (tiling, VMEM limits, device memory).  Nothing runs, so these
+``jax.experimental.topologies`` describes, which catches what a CPU
+compile cannot (the TPU's layouts and its device memory).  Nothing runs, so these
 tests say nothing about results or times.
 
 The topology is described inside a module fixture, never at import: only
@@ -12,16 +12,14 @@ compiles, because an entry compiled for a described chip cannot be read
 back without one.
 """
 
+import collections
 import os
+import re
 
-import numpy as np
 import pytest
 
 import jax
-import jax.numpy as jnp
 from jax.sharding import SingleDeviceSharding
-
-from repro.kernels.fluidstep import ops
 
 #: one v5e chip's device memory
 HBM_BYTES = 16 * 1024**3
@@ -57,13 +55,6 @@ def no_cache():
     compilation_cache.reset_cache()
 
 
-@pytest.fixture()
-def on_tpu(monkeypatch):
-    """The program picks its kernel from the default backend, which is the
-    CPU here: steer it to the TPU branch for the duration of a test."""
-    monkeypatch.setattr(ops, "backend_platform", lambda: "tpu")
-
-
 def _lane_batch(name):
     """One lane of ``name`` at its registered size, stacked as the
     Monte-Carlo driver stacks seeds."""
@@ -73,7 +64,7 @@ def _lane_batch(name):
 
     scn = get_scenario(name, seed=0)
     batch = stack_traces([trace_from_jobs(scn.job_list(), fusion=scn.fusion)])
-    return scn, batch, fluid_config(scn, kernel="tpu")
+    return scn, batch, fluid_config(scn)
 
 
 def _spec(x, lanes, sharding):
@@ -81,52 +72,60 @@ def _spec(x, lanes, sharding):
                                 sharding=sharding)
 
 
-@pytest.mark.parametrize("name", ["paper", "oversub_fabric"])
-def test_step_kernel_compiles(name, one_chip, no_cache):
-    from repro.core.topology import nic_topology
-    from repro.kernels.fluidstep.kernel import fluid_step_core_pallas
-
-    scn = _lane_batch(name)[0]
-    topo = scn.topology if scn.topology is not None else nic_topology(scn.n_servers)
-    n_jobs, n_servers = scn.n_jobs, scn.n_servers
-    n_domains = np.asarray(topo.incidence()).shape[0]
-
-    def sds(shape, dtype):
-        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
-
-    args = (
-        sds((n_jobs, n_domains), jnp.bool_),   # loads
-        sds((n_jobs, n_servers), jnp.float32),  # member
-        sds((n_jobs,), jnp.bool_),              # active
-        sds((n_jobs,), jnp.float32),            # rem
-        sds((n_servers,), jnp.float32),         # bw
-        sds((n_domains,), jnp.float32),         # oversub
-    )
-    p = scn.params
-    compiled = fluid_step_core_pallas.lower(
-        *args, b=p.b, eta=p.eta, interpret=False
-    ).compile()
-    assert "tpu_custom_call" in compiled.as_text()
-
-
-@pytest.mark.parametrize("name", ["paper", "model_zoo"])
-def test_chunk_program_compiles_with_kernel(name, one_chip, no_cache, on_tpu):
+def _chunk_program(name, lanes, sharding):
+    """``_chunk_jit`` for ``name`` at ``lanes`` lanes, compiled."""
     from repro.core.jaxsim import _chunk_jit, _init_jit, _policy_args
 
     _, batch, cfg = _lane_batch(name)
     max_ways, gated, cfg_key = _policy_args(cfg)
-    assert cfg_key.kernel == "tpu"
-    traces = {k: _spec(v, LANES, one_chip) for k, v in batch.items()}
+    traces = {k: _spec(v, lanes, sharding) for k, v in batch.items()}
     state = jax.eval_shape(lambda tr: _init_jit(tr, cfg_key), traces)
-    state = {k: _spec(v, LANES, one_chip) for k, v in state.items()}
-    scalars = [jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip)
+    state = {k: _spec(v, lanes, sharding) for k, v in state.items()}
+    scalars = [jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=sharding)
                for x in (max_ways, gated)]
-    compiled = _chunk_jit.lower(traces, state, cfg_key, *scalars).compile()
-    assert "tpu_custom_call" in compiled.as_text()
-    mem = compiled.memory_analysis()
+    return _chunk_jit.lower(traces, state, cfg_key, *scalars).compile()
+
+
+#: an HLO array type: element type, dimensions, minor-to-major layout
+_ARRAY = re.compile(r"\b[a-z]+[0-9]*\[([0-9,]*)\]\{([0-9,]*)")
+
+
+def _layouts(text, dims):
+    """How often each minor-to-major layout is given to arrays of
+    ``dims`` in compiled HLO ``text``."""
+    want = ",".join(map(str, dims))
+    return collections.Counter(
+        layout for shape, layout in _ARRAY.findall(text) if shape == want)
+
+
+@pytest.mark.parametrize("lanes", [256, LANES])
+@pytest.mark.parametrize("name", ["paper", "oversub_fabric"])
+def test_chunk_program_keeps_lane_minor_layouts(name, lanes, one_chip,
+                                                no_cache):
+    """Nothing in the step pins a layout: the chunk program launches no
+    Pallas kernel, has no (lanes, jobs, 1) array, and every (lanes, jobs, servers) and
+    (lanes, jobs) array of the scan has the lane axis minor, so that it
+    fills whole 128-lane tiles."""
+    scn = _lane_batch(name)[0]
+    text = _chunk_program(name, lanes, one_chip).as_text()
+    assert "tpu_custom_call" not in text
+    jobs, servers = scn.n_jobs, scn.n_servers
+    assert not _layouts(text, (lanes, jobs, 1))
+    for dims in [(lanes, jobs, servers), (lanes, jobs)]:
+        got = _layouts(text, dims)
+        assert got, dims
+        assert all(layout.split(",")[0] == "0" for layout in got), (dims, got)
+
+
+@pytest.mark.parametrize("lanes", [1, 32, 256, LANES])
+@pytest.mark.parametrize("name", ["paper", "oversub_fabric", "model_zoo"])
+def test_chunk_program_compiles(name, lanes, one_chip, no_cache):
+    """The chunk program at one lane, at fewer lanes than a tile, and at
+    two and eight 128-lane tiles fits one chip's memory."""
+    mem = _chunk_program(name, lanes, one_chip).memory_analysis()
     total = mem.argument_size_in_bytes + mem.temp_size_in_bytes
     assert total < HBM_BYTES, (
-        f"{name} at {LANES} lanes needs {total / 2**30:.2f} GiB "
+        f"{name} at {lanes} lanes needs {total / 2**30:.2f} GiB "
         f"(arguments {mem.argument_size_in_bytes}, "
         f"temporaries {mem.temp_size_in_bytes})"
     )
