@@ -5,7 +5,8 @@
 
 For each seed, after the cell's warm-up: one query of the window through
 the program, and the rollouts that a run of that seed would sample, run
-by the plain reference and by two controls put in the program's place:
+by the configuration's plain reference (``workload.reference_of``) and by
+two controls put in the program's place:
 
 * ``bf16``: the reference with its continuous quantities (remaining phase
   time, phase lengths, drain fraction) in bfloat16, the precision below
@@ -32,7 +33,7 @@ from pathlib import Path
 CHECKOUT = Path(__file__).resolve().parents[1]
 sys.path[:0] = [str(CHECKOUT), str(CHECKOUT / "src")]
 
-from perfbench.lib import cell, check, reference, workload  # noqa: E402
+from perfbench.lib import cell, check, workload  # noqa: E402
 
 
 def controls(cfg: dict) -> dict:
@@ -65,15 +66,16 @@ def readings(program, cfg: dict, traffic: dict, seed: int,
     picked = cell.sample([query], traffic["sample_lanes"], seed)
     lanes = [workload.job_arrays(workload.generate(cfg, s)) for s, _ in picked]
     prog = [(r.n_finished, r.avg_jct, r.makespan) for _, r in picked]
+    simulate = workload.reference_of(cfg)
     t0 = time.perf_counter()
-    ref = reference.simulate(lanes, cfg)
+    ref = simulate(lanes, cfg)
     out["reference_s"] = time.perf_counter() - t0
     out["program"] = judged(prog, ref, cfg["n_jobs"])
     for name, (ccfg, ftype) in controls(cfg).items():
         if not with_controls:
             break
         t0 = time.perf_counter()
-        ctl = reference.simulate(lanes, ccfg, ftype=ftype)
+        ctl = simulate(lanes, ccfg, ftype=ftype)
         as_prog = [(*check.summarize(c["jct"], c["finished"]),
                     float(c["makespan"])) for c in ctl]
         out[name] = {**judged(as_prog, ref, cfg["n_jobs"]),

@@ -26,7 +26,7 @@ from types import SimpleNamespace
 
 import numpy as np
 
-from perfbench.lib import check, reference, workload
+from perfbench.lib import check, workload
 
 #: jax.monitoring event recorded for every program the backend compiles,
 #: or loads from the persistent compilation cache
@@ -208,9 +208,9 @@ def window(program: Program, traffic: dict, seed: int, seconds: float,
 
 def traced_query(program: Program, jax, traffic: dict, seed: int, errors,
                  counter: CompileCounter):
-    """One query of the window under the profiler; returns the query and
-    the trace's reduction."""
-    from perfbench.lib import trace
+    """One query of the window under the profiler; returns the query, the
+    trace's reduction and its program spans (``lib/spans.py``)."""
+    from perfbench.lib import spans, trace
 
     seeds = query_seeds(seed, 0, traffic["lanes"])
     before = counter.count
@@ -224,11 +224,13 @@ def traced_query(program: Program, jax, traffic: dict, seed: int, errors,
                 recs, took = run_query(program, seeds, errors)
         finally:
             jax.profiler.stop_trace()
-        summary = trace.reduce_dir(tdir, QUERY_SPAN)
+        summary, pd = trace.reduce_dir(tdir, QUERY_SPAN)
+        program_spans = spans.reduce(pd, QUERY_SPAN)
     finally:
         shutil.rmtree(tdir, ignore_errors=True)
-    return SimpleNamespace(seeds=seeds, recs=recs, seconds=took,
-                           compiles=counter.count - before), summary
+    query = SimpleNamespace(seeds=seeds, recs=recs, seconds=took,
+                            compiles=counter.count - before)
+    return query, summary, program_spans
 
 
 def sample(queries: list, k: int, seed: int) -> list:
@@ -254,7 +256,7 @@ def compare(cfg: dict, queries: list, traffic: dict, seed: int) -> dict:
     )
     picked = sample(queries, traffic["sample_lanes"], seed)
     lanes = [workload.job_arrays(workload.generate(cfg, s)) for s, _ in picked]
-    ref = reference.simulate(lanes, cfg) if lanes else []
+    ref = workload.reference_of(cfg)(lanes, cfg) if lanes else []
     prog = [(r.n_finished, r.avg_jct, r.makespan) for _, r in picked]
     off = check.lanes_off(prog, ref)
     return {"lanes_unfinished": unfinished, "lanes_off": off}
@@ -272,8 +274,9 @@ def memory_peak(devices) -> int:
 
 
 def read_metrics(names: list, ctx) -> dict:
-    """Per-layer metrics: each is read by ``metrics/<name>.py``; one that
-    finds nothing to read is left out."""
+    """Per-layer metrics: each is read by ``metrics/<name>.py`` from
+    ``ctx`` (:func:`measure`); one that finds nothing to read is left
+    out."""
     out = {}
     for name, unit in names:
         value = importlib.import_module(f"perfbench.metrics.{name}").read(ctx)
@@ -303,10 +306,10 @@ def measure(cell: dict, cfg: dict, traffic: dict, metrics: dict, *,
         warm = warm_up(program, cfg, traffic, counter)
         setup_s = time.perf_counter() - t_start
         errors = []
-        summary = None
+        summary = program_spans = None
         if trace:
-            query, summary = traced_query(program, jax, traffic, seed, errors,
-                                          counter)
+            query, summary, program_spans = traced_query(
+                program, jax, traffic, seed, errors, counter)
             queries, elapsed = [query], query.seconds
         else:
             queries, elapsed, errors = window(program, traffic, seed, seconds,
@@ -322,9 +325,14 @@ def measure(cell: dict, cfg: dict, traffic: dict, metrics: dict, *,
                     for r in q.recs)
     values = compare(cfg, queries, traffic, seed)
     checks, ok = check.judge(values)
+    # what a per-layer metric reads: each query's RunMetrics (None for a
+    # query that raised), and in a traced run the trace's reduction and
+    # its program spans
     ctx = SimpleNamespace(
+        records=[q.recs for q in queries],
         chunks=[q.recs[0].chunks for q in queries if q.recs],
         compiles=sum(q.compiles for q in queries), trace=summary,
+        spans=program_spans,
     )
     if trace:
         out = read_metrics(metrics["per_layer"], ctx)

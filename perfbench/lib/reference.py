@@ -7,26 +7,35 @@ this order:
 
 1. admission: of the queued jobs that have arrived (``arrival < t``) and
    fit the free GPUs, the one with the smallest remaining service
-   ``iters * t_iter * gpus`` takes GPUs, servers with the most free GPUs
-   first (ties by server index); it starts computing;
-2. contention: each job's ring loads the NIC of every server it holds GPUs
-   on, if it holds GPUs on more than one; a transfer in flight sees
-   ``k`` = the most transfers in flight on any NIC it loads, and drains
-   at the Eq. 5 fraction ``b / (k b + (k - 1) eta)`` of its nominal rate,
-   scaled by its slowest member server's bandwidth;
+   ``iters * t_iter * gpus`` takes GPUs, servers in the order of the
+   configuration's placement rank (:data:`RANKS`; ties by server index);
+   it starts computing;
+2. contention: each job's ring loads every contention domain of the
+   fabric (:mod:`perfbench.lib.fabric`) that it crosses, holding GPUs on
+   servers both inside and outside it (on a NIC domain: the job's server,
+   if it holds GPUs on more than one); a transfer in flight sees ``k`` =
+   the most transfers in flight on any domain it loads, each count times
+   that domain's oversubscription, and drains at the Eq. 5 fraction
+   ``b / (k b + (k - 1) eta)`` of its nominal rate, scaled by its slowest
+   member server's bandwidth;
 3. compute drains by dt; a finished compute phase of a multi-server job
    waits for its all-reduce, that of a one-server job ends the iteration;
-4. gating (Ada-SRSF): a waiting all-reduce may start if uncontended, or,
-   under at most ``max_ways`` transfers, if its size is below
-   ``dual_threshold`` times the smallest remainder of the transfers it
-   would share a NIC with; of those, the one with the least remaining
-   service starts;
+4. gating: a waiting all-reduce may start if the policy's gating test
+   passes it (:func:`threshold_gate`: Ada-SRSF and SRSF(n)); the test
+   reads the raw count of transfers it would share a domain with,
+   ``k_new`` (the most in flight on a domain it loads, plus itself), and
+   the smallest remainder among them; of those it passes, the one with
+   the least remaining service starts;
 5. transfers in flight drain; a finished one ends the iteration; a job
    whose iterations are done frees its GPUs at ``t``.
 
 No step is skipped, no rollouts are batched together beyond ``vmap``, and
 there is no kernel: this is the semantics that the program's chunked,
 skipping, compacting driver has to reproduce.
+
+A reference of another placement or gating policy
+(``perfbench/references/``) imports this module and passes its own rank
+or gating test to :func:`simulate` through ``ranks`` or ``gates``.
 
 ``ftype`` is the precision of the continuous quantities (remaining phase
 time, phase lengths, drain fraction).  The clock, arrival and finish times
@@ -37,11 +46,13 @@ a coarser type would stop the clock rather than round the physics.
 from __future__ import annotations
 
 import functools
-from typing import NamedTuple
+from typing import Callable, NamedTuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+
+from perfbench.lib import fabric
 
 QUEUED, COMPUTE, COMM, DONE = 0, 1, 2, 3
 
@@ -55,16 +66,44 @@ def policy_params(name: str) -> tuple:
     raise ValueError(f"the reference models 'ada' and 'srsfN', not {name!r}")
 
 
+def lwf_rank(free, m: "Model"):
+    """LWF: the servers with the most free GPUs first (ties by server
+    index).  Admission fills servers in ascending order of the rank."""
+    return -free
+
+
+#: placement name -> rank of the servers at an admission
+RANKS = {"lwf": lwf_rank}
+#: gating policy name -> test; a policy not named takes :func:`threshold_gate`
+GATES = {}
+
+
+def threshold_gate(c: dict, m: "Model"):
+    """Ada-SRSF (``ada``) and SRSF(n) (``srsfN``): a waiting all-reduce may
+    start if uncontended, or under at most ``max_ways`` transfers if (for
+    ``ada``) its contention-free time is below Theorem 2's ratio of the
+    smallest remainder it would share a domain with.  ``c`` holds the
+    tick's contention state (:func:`_lane`, step 2)."""
+    max_ways, ratio_tested = policy_params(m.policy)
+    threshold = m.b / (2.0 * (m.b + m.eta))  # Theorem 2
+    k_new = c["k_new"]
+    return (k_new <= 1) | (k_new <= max_ways) & (
+        (c["comm"] < threshold * c["old_min"]) | (not ratio_tested)
+    )
+
+
 def _lane(lane, m: "Model", ftype):
     """Run one rollout to its end; ``lane`` holds its job arrays."""
     f32 = jnp.float32
     a, b, eta, dt = m.a, m.b, m.eta, m.dt
-    max_ways, ratio_tested = policy_params(m.policy)
-    threshold = b / (2.0 * (b + eta))  # Theorem 2
     n_servers, per_server = m.n_servers, m.gpus_per_server
     bw = np.ones((n_servers,), np.float32)
     bw[: len(m.server_bandwidth)] = m.server_bandwidth[:n_servers]
     bw = jnp.asarray(bw)
+    inside = np.zeros((len(m.domains), n_servers), bool)  # domain x server
+    for d, (members, _) in enumerate(m.domains):
+        inside[d, list(members)] = True
+    oversub = jnp.asarray([x for _, x in m.domains], ftype)
 
     arrival = lane["arrival"]
     gpus = lane["n_gpus"]
@@ -90,7 +129,7 @@ def _lane(lane, m: "Model", ftype):
         key = jnp.where(queued, iters * t_iter.astype(f32) * gpus_f, jnp.inf)
         pick = jnp.argmin(jnp.where(ready, key, jnp.inf))
         admit = ready[pick] & (free.sum() >= gpus_f[pick])
-        order = jnp.argsort(-free, stable=True)
+        order = jnp.argsort(m.rank(free, m), stable=True)
         free_sorted = free[order]
         before = jnp.cumsum(free_sorted) - free_sorted
         take_sorted = jnp.clip(gpus_f[pick] - before, 0.0, free_sorted)
@@ -106,19 +145,22 @@ def _lane(lane, m: "Model", ftype):
         # 2. contention
         holds = servers > 0
         multi = holds.sum(axis=1) > 1
-        loads = holds & multi[:, None]
+        held_in = (holds[:, None, :] & inside[None]).any(axis=2)
+        held_out = (holds[:, None, :] & ~inside[None]).any(axis=2)
+        loads = held_in & held_out  # (job, domain): its ring crosses the cut
         in_comm = phase == COMM
         active = in_comm & started & (rem > 0)
-        per_nic = (loads & active[:, None]).sum(axis=0)  # transfers per NIC
+        per_dom = (loads & active[:, None]).sum(axis=0)  # transfers per domain
+        weighted = per_dom.astype(ftype) * oversub
         k_eff = jnp.maximum(
-            jnp.where(loads, per_nic[None, :], 0).max(axis=1), 1
+            jnp.where(loads, weighted[None, :], 0).max(axis=1), 1
         ).astype(ftype)
-        k_new = jnp.maximum(jnp.where(loads, per_nic[None, :] + 1, 0).max(axis=1), 1)
+        k_new = jnp.maximum(jnp.where(loads, per_dom[None, :] + 1, 0).max(axis=1), 1)
         slowest = jnp.where(holds, bw[None, :], jnp.inf).min(axis=1)
         slowest = jnp.where(holds.any(axis=1), slowest, 1.0).astype(ftype)
         frac = slowest * (b / (k_eff * b + (k_eff - 1) * eta))
-        nic_min = jnp.where(loads & active[:, None], rem[:, None], jnp.inf).min(axis=0)
-        old_min = jnp.where(loads, nic_min[None, :], jnp.inf).min(axis=1)
+        dom_min = jnp.where(loads & active[:, None], rem[:, None], jnp.inf).min(axis=0)
+        old_min = jnp.where(loads, dom_min[None, :], jnp.inf).min(axis=1)
 
         # 3. compute
         computing = phase == COMPUTE
@@ -129,10 +171,9 @@ def _lane(lane, m: "Model", ftype):
 
         # 4. gating
         waiting = in_comm & ~started
-        ok = (k_new <= 1) | (k_new <= max_ways) & (
-            (comm < threshold * old_min) | (not ratio_tested)
-        )
-        ok = waiting & ok
+        ok = waiting & m.gate(
+            {"comm": comm, "k_new": k_new, "old_min": old_min, "loads": loads,
+             "active": active, "rem": rem}, m)
         first = jnp.argmin(jnp.where(ok, service, jnp.inf))
         started = started | ((jobs == first) & ok)
 
@@ -181,8 +222,9 @@ def _lane(lane, m: "Model", ftype):
 
 
 class Model(NamedTuple):
-    """The configuration's numbers that the model reads (hashable, so a
-    compiled reference is reused across calls)."""
+    """The configuration's numbers that the model reads, with the rank
+    and gating test of its placement and policy (hashable, so a compiled
+    reference is reused across calls)."""
 
     a: float
     b: float
@@ -193,16 +235,24 @@ class Model(NamedTuple):
     n_servers: int
     gpus_per_server: int
     max_steps: int
+    domains: tuple  # fabric.domains: ((servers, oversub), ...)
+    rank: Callable
+    gate: Callable
 
     @classmethod
-    def of(cls, cfg: dict) -> "Model":
-        if cfg["topology"] != "nic":
-            raise ValueError(f"the reference models one NIC domain per "
-                             f"server, not topology {cfg['topology']!r}")
+    def of(cls, cfg: dict, ranks: dict = RANKS, gates: dict = GATES) -> "Model":
+        placement, policy = cfg["placement"], cfg["policy"]
+        if placement not in ranks:
+            raise ValueError(f"the reference models placements "
+                             f"{sorted(ranks)}, not {placement!r}")
+        if policy not in gates:
+            policy_params(policy)  # raises for a policy it does not model
         c = cfg["contention"]
         return cls(c["a"], c["b"], c["eta"], tuple(c["server_bandwidth"]),
-                   cfg["dt"], cfg["policy"], cfg["n_servers"],
-                   cfg["gpus_per_server"], cfg["max_steps"])
+                   cfg["dt"], policy, cfg["n_servers"],
+                   cfg["gpus_per_server"], cfg["max_steps"],
+                   fabric.domains(cfg), ranks[placement],
+                   gates.get(policy, threshold_gate))
 
 
 @functools.partial(jax.jit, static_argnames=("model", "ftype"))
@@ -210,11 +260,13 @@ def _run(lanes, model, ftype):
     return jax.vmap(lambda lane: _lane(lane, model, ftype))(lanes)
 
 
-def simulate(lanes: list, cfg: dict, ftype=jnp.float32, block: int = 64) -> list:
+def simulate(lanes: list, cfg: dict, ftype=jnp.float32, block: int = 64, *,
+             ranks: dict = RANKS, gates: dict = GATES) -> list:
     """Reference rollouts of ``lanes`` (job-array dicts of equal job
     count), ``block`` at a time; returns per lane a dict of numpy
-    ``jct``, ``finished`` and ``makespan``."""
-    model = Model.of(cfg)
+    ``jct``, ``finished`` and ``makespan``.  ``ranks`` and ``gates`` map
+    the placement and policy names to their rules."""
+    model = Model.of(cfg, ranks, gates)
     out = []
     for start in range(0, len(lanes), block):
         part = lanes[start:start + block]

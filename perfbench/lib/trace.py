@@ -7,10 +7,6 @@ What a TPU trace of this program holds (read by hand on a v5e trace):
   ``jit__chunk_jit(...)``) and a line ``XLA Ops`` (one event per HLO
   instruction executed, named by its HLO text ``%<name>.<n> = ...``;
   control flow such as ``%while`` spans the instructions of its body);
-* the step kernel as ``XLA Ops`` events of the instruction
-  ``%fluid_step_core_pallas.<n>``, a ``custom-call`` with
-  ``custom_call_target="tpu_custom_call"`` (the Pallas kernel, launched
-  from ``jit(fluid_step_core_pallas)``);
 * a plane ``/host:CPU`` whose lines are host threads; the benchmark's
   ``mc_query`` span sits on the Python thread's line beside jax's own
   events there (``PjitFunction(...)``, ``np.asarray(jax.Array)``, ...).
@@ -29,8 +25,6 @@ from collections import defaultdict
 MODULES = "XLA Modules"
 OPS = "XLA Ops"
 CHUNK_PROGRAM = "jit__chunk_jit("
-STEP_KERNEL = "fluid_step_core_pallas"
-PALLAS_TARGET = 'custom_call_target="tpu_custom_call"'
 TOP = 10
 
 
@@ -40,7 +34,6 @@ class Summary:
     window_s: float  # summed length of the query spans
     busy_s: float  # union of program executions in them, mean over chips
     chunk_s: float  # chunk program's device time, mean over chips
-    kernel_s: float  # step kernel's device time, mean over chips
     lead_s: float | None  # span starts to their first chunk program
     breakdown: dict
 
@@ -81,20 +74,16 @@ def inside(start, spans) -> bool:
 def leaf_op_times(line, spans) -> tuple:
     """Device time per instruction name over the ``XLA Ops`` events that
     start inside ``spans`` and contain no other event (control-flow
-    instructions span their bodies' instructions), and the step
-    kernel's share of it.  Streams the line: its events come in order of
-    start, and their HLO texts are too many to hold."""
+    instructions span their bodies' instructions).  Streams the line: its
+    events come in order of start, and their HLO texts are too many to
+    hold."""
     times = defaultdict(float)
-    kernel = 0.0
-    stack = []  # [end, name, duration, is_kernel, has_child]
+    stack = []  # [end, name, duration, has_child]
     last = None
 
     def close(entry):
-        nonlocal kernel
-        if not entry[4]:
+        if not entry[3]:
             times[entry[1]] += entry[2]
-            if entry[3]:
-                kernel += entry[2]
 
     for e in line.events:
         s, end = e.start_ns, e.end_ns
@@ -110,14 +99,11 @@ def leaf_op_times(line, spans) -> tuple:
             close(stack.pop())
             has_child = True
         if stack:
-            stack[-1][4] = True
-        text = e.name
-        name = op_name(text)
-        is_kernel = name.startswith(STEP_KERNEL) and PALLAS_TARGET in text
-        stack.append([end, name, end - s, is_kernel, has_child])
+            stack[-1][3] = True
+        stack.append([end, op_name(e.name), end - s, has_child])
     while stack:
         close(stack.pop())
-    return times, kernel
+    return times
 
 
 def device_planes(pd) -> list:
@@ -168,7 +154,7 @@ def reduce(pd, span: str) -> Summary:
             f"trace holds {len(spans)} {span!r} spans and {len(planes)} "
             "device planes with program executions"
         )
-    busy = chunk = kernel = 0.0
+    busy = chunk = 0.0
     op_time = defaultdict(float)
     first_busy = None
     for k, plane in enumerate(planes):
@@ -178,9 +164,7 @@ def reduce(pd, span: str) -> Summary:
         chunk += sum(e - s for s, e, n in modules if n.startswith(CHUNK_PROGRAM))
         ops = line(plane, OPS)
         if ops is not None:
-            times, in_kernel = leaf_op_times(ops, spans)
-            kernel += in_kernel
-            for name, t in times.items():
+            for name, t in leaf_op_times(ops, spans).items():
                 op_time[name] += t
         if k == 0:
             first_busy, chunk_starts = union, sorted(
@@ -198,7 +182,6 @@ def reduce(pd, span: str) -> Summary:
         window_s=sum(b - a for a, b in spans) / 1e9,
         busy_s=busy / n / 1e9,
         chunk_s=chunk / n / 1e9,
-        kernel_s=kernel / n / 1e9,
         lead_s=(None if None in lead else
                 sum(c - a for c, (a, _) in zip(lead, spans)) / 1e9),
         breakdown={
@@ -210,8 +193,10 @@ def reduce(pd, span: str) -> Summary:
     )
 
 
-def reduce_dir(trace_dir: str, span: str) -> Summary:
-    """Reduce the one ``.xplane.pb`` that a profiler session wrote."""
+def reduce_dir(trace_dir: str, span: str) -> tuple:
+    """Read the one ``.xplane.pb`` that a profiler session wrote; returns
+    its reduction and the ``ProfileData``, for other readers of the same
+    trace."""
     from jax.profiler import ProfileData
 
     paths = glob.glob(os.path.join(trace_dir, "**", "*.xplane.pb"),
@@ -219,4 +204,5 @@ def reduce_dir(trace_dir: str, span: str) -> Summary:
     if len(paths) != 1:
         raise RuntimeError(f"expected one .xplane.pb under {trace_dir}, "
                            f"found {len(paths)}")
-    return reduce(ProfileData.from_file(paths[0]), span)
+    pd = ProfileData.from_file(paths[0])
+    return reduce(pd, span), pd

@@ -3,9 +3,11 @@
 A configuration is ``configs/<name>.json``; it names a generator
 (``generators/<generator>.py``) that is the benchmark's own copy of the
 program's job generator, so the inputs of the reference never come from
-the program.  :func:`check_program_jobs` is the workload guard: at set-up
-the program's scenario registry has to yield, seed for seed, the jobs and
-the cluster that the configuration states, or the run stops.
+the program, and may name its own plain reference
+(``references/<reference>.py``, :func:`reference_of`).
+:func:`check_program_jobs` is the workload guard: at set-up the program's
+scenario registry has to yield, seed for seed, the jobs and the cluster,
+fabric included, that the configuration states, or the run stops.
 """
 
 from __future__ import annotations
@@ -16,6 +18,8 @@ import json
 from pathlib import Path
 
 import numpy as np
+
+from perfbench.lib import fabric
 
 ROOT = Path(__file__).resolve().parents[1]
 
@@ -42,6 +46,16 @@ def generate(cfg: dict, seed: int) -> list:
     iterations, model_index)`` in arrival order."""
     gen = importlib.import_module(f"perfbench.generators.{cfg['generator']}")
     return gen.jobs(seed, cfg, len(model_table()))
+
+
+def reference_of(cfg: dict):
+    """``simulate(lanes, cfg, ftype=..., block=...)`` of the
+    configuration's plain reference: ``references/<reference>.py`` where
+    the configuration names one, else :mod:`perfbench.lib.reference`."""
+    name = cfg.get("reference")
+    module = (f"perfbench.references.{name}" if name is not None
+              else "perfbench.lib.reference")
+    return importlib.import_module(module).simulate
 
 
 def job_arrays(jobs: list) -> dict:
@@ -84,13 +98,24 @@ def program_rows(scn) -> list:
 def cluster_row(cfg: dict) -> tuple:
     c = cfg["contention"]
     return (cfg["n_servers"], cfg["gpus_per_server"], c["a"], c["b"],
-            c["eta"], tuple(c["server_bandwidth"]), None, "all", None)
+            c["eta"], tuple(c["server_bandwidth"]), fabric.domains(cfg),
+            cfg.get("fusion", "all"), cfg.get("chaos"))
+
+
+def program_domains(scn) -> tuple:
+    """The scenario's contention domains in :func:`fabric.domains`' form;
+    no topology is one NIC domain per server."""
+    if scn.topology is None:
+        return tuple(((s,), 1.0) for s in range(scn.n_servers))
+    return tuple((d.servers, float(d.oversub))
+                 for d in scn.topology.domains)
 
 
 def program_cluster_row(scn) -> tuple:
     p = scn.params
     return (scn.n_servers, scn.gpus_per_server, p.a, p.b, p.eta,
-            tuple(p.server_bandwidth), scn.topology, scn.fusion, scn.chaos)
+            tuple(p.server_bandwidth), program_domains(scn), scn.fusion,
+            scn.chaos)
 
 
 def check_program_jobs(cfg: dict, seeds, get_scenario) -> None:

@@ -1,5 +1,6 @@
 """The workload guard: the program's generator has to draw the jobs that
-the benchmark's own copy draws, or set-up stops."""
+the benchmark's own copy draws, and its scenario has to build the fabric
+that the configuration states, or set-up stops."""
 
 import dataclasses
 
@@ -40,3 +41,31 @@ def test_changed_generator_fails(changed):
     cfg = workload.load_config("paper")
     with pytest.raises(RuntimeError, match="workload guard"):
         workload.check_program_jobs(cfg, [0, 1, 2], changed)
+
+
+def fabric_config():
+    return workload.load_json(workload.ROOT / "tests" / "data" / "fabric.json")
+
+
+def test_program_builds_the_stated_fabric():
+    workload.check_program_jobs(fabric_config(), [0, 2**31 + 11], get_scenario)
+
+
+def _other_oversub(cfg):
+    cfg["topology"] = {**cfg["topology"], "oversub": 2}
+
+
+def _other_rack_size(cfg):
+    cfg["topology"] = {**cfg["topology"], "servers_per_rack": 4}
+
+
+def _nic_stated(cfg):
+    cfg["topology"] = "nic"
+
+
+@pytest.mark.parametrize("change", [_other_oversub, _other_rack_size, _nic_stated])
+def test_fabric_the_program_does_not_build_fails(change):
+    cfg = fabric_config()
+    change(cfg)
+    with pytest.raises(RuntimeError, match="workload guard"):
+        workload.check_program_jobs(cfg, [0], get_scenario)
