@@ -116,6 +116,15 @@ _TICK_MARGIN = 1e-2
 #: "No event" sentinel for per-job tick caps (far above any max_steps).
 _BIG_TICKS = 1 << 30
 
+#: per-lane counters of a fabric with uplink domains
+#: (:meth:`Topology.uplink_mask`), kept in the lane state and returned by
+#: :func:`simulate_traces_batched`: admissions whose ring crosses a rack
+#: boundary (``uplink_jobs``), and transfers in flight at the end of each
+#: tick, summed over ticks: those loading an uplink
+#: (``uplink_transfer_ticks``) and all of them (``transfer_ticks``).  A
+#: NIC-only fabric has no uplink and its lanes carry none of them.
+UPLINK_COUNTERS = ("uplink_jobs", "uplink_transfer_ticks", "transfer_ticks")
+
 
 @dataclasses.dataclass(frozen=True)
 class JaxSimConfig:
@@ -252,8 +261,16 @@ def _ticks_to_zero(x, inv_dt):
     return jnp.floor(x * inv_dt - _TICK_MARGIN).astype(jnp.int32) + 1
 
 
+def _fabric(cfg: JaxSimConfig) -> Topology:
+    """The configured fabric; none is one NIC domain per server."""
+    if cfg.topology is not None:
+        return cfg.topology
+    return nic_topology(cfg.n_servers)
+
+
 def _init_lane_state(trace: Dict[str, jnp.ndarray], cfg: JaxSimConfig):
-    """Initial per-lane state (legacy layout + the tick counter ``i``)."""
+    """Initial per-lane state (legacy layout + the tick counter ``i``,
+    and the :data:`UPLINK_COUNTERS` where the fabric has an uplink)."""
     n_jobs = trace["arrival"].shape[0]
     ns = cfg.n_servers
     valid = trace.get("valid")
@@ -261,7 +278,7 @@ def _init_lane_state(trace: Dict[str, jnp.ndarray], cfg: JaxSimConfig):
         valid = jnp.ones((n_jobs,), bool)
     bucket_bytes = trace.get("bucket_bytes")
     wfbp = bucket_bytes is not None and int(bucket_bytes.shape[-1]) > 1
-    topo = cfg.topology if cfg.topology is not None else nic_topology(ns)
+    topo = _fabric(cfg)
     n_domains = np.asarray(topo.incidence()).shape[0]
     state = {
         "phase": jnp.where(valid, QUEUED, DONE).astype(jnp.int32),
@@ -281,6 +298,8 @@ def _init_lane_state(trace: Dict[str, jnp.ndarray], cfg: JaxSimConfig):
     }
     if wfbp:
         state["bucket"] = jnp.zeros((n_jobs,), jnp.int32)
+    if topo.uplink_mask().any():
+        state.update(dict.fromkeys(UPLINK_COUNTERS, jnp.asarray(0, jnp.int32)))
     return state
 
 
@@ -301,7 +320,7 @@ def _make_lane_step(trace: Dict[str, jnp.ndarray], cfg: JaxSimConfig,
     # Fabric topology as STATIC matrices (cfg is jit-static, so these are
     # compile-time constants): domain incidence (n_domains, n_servers),
     # per-domain oversubscription, and each server's rack for rack_pack.
-    topo = cfg.topology if cfg.topology is not None else nic_topology(ns)
+    topo = _fabric(cfg)
     if topo.n_servers != ns:
         raise ValueError(
             f"topology covers {topo.n_servers} servers, config has {ns}"
@@ -309,6 +328,10 @@ def _make_lane_step(trace: Dict[str, jnp.ndarray], cfg: JaxSimConfig,
     incidence = jnp.asarray(topo.incidence(), jnp.float32)
     inc_t = incidence.T  # (S, D) for the incremental loads-row update
     oversub = jnp.asarray(topo.oversub_array(), jnp.float32)
+    # static: a fabric without uplinks keeps no counters, and its step
+    # graph is the one it had before they existed
+    uplink = topo.uplink_mask()
+    counted = bool(uplink.any())
     server_rack = jnp.asarray(topo.server_rack(), jnp.int32)
     n_racks = len(topo.rack_groups())
     place_key = jax.random.PRNGKey(cfg.placement_seed)
@@ -582,8 +605,26 @@ def _make_lane_step(trace: Dict[str, jnp.ndarray], cfg: JaxSimConfig,
         }
         if wfbp:
             new_state["bucket"] = bucket
+        if counted:
+            new_state["uplink_jobs"] = st["uplink_jobs"] + (
+                admit & jnp.any(row_loads & uplink)).astype(jnp.int32)
+            flying = (phase == COMM) & started & (rem > 0)
+            n_flying = flying.sum(dtype=jnp.int32)
+            n_on_uplink = (flying & jnp.any(loads & uplink, axis=1)).sum(
+                dtype=jnp.int32)
+
+        def count_transfers(state, ticks):
+            """Add the transfers in flight at the end of each of
+            ``ticks`` ticks: no transfer starts or ends inside a skip, so
+            each skipped tick ends with the executed tick's."""
+            if counted:
+                state["transfer_ticks"] = st["transfer_ticks"] + ticks * n_flying
+                state["uplink_transfer_ticks"] = (
+                    st["uplink_transfer_ticks"] + ticks * n_on_uplink)
+            return state
+
         if not cfg.skip:
-            return new_state
+            return count_transfers(new_state, 1)
 
         # ---- next-event skip: bulk-advance eventless ticks ------------------
         # An executed tick is exactly the legacy tick above; ``extra`` is a
@@ -675,7 +716,7 @@ def _make_lane_step(trace: Dict[str, jnp.ndarray], cfg: JaxSimConfig,
         )
         new_state["i"] = new_state["i"] + extra
         new_state["t"] = new_state["i"].astype(jnp.float32) * cfg.dt
-        return new_state
+        return count_transfers(new_state, 1 + extra)
 
     return step
 
@@ -729,13 +770,15 @@ def _drive_batched(traces: Dict[str, jnp.ndarray], cfg: JaxSimConfig,
     (lanes at each launch, summed over launches: all of them, padding
     included, and those not yet retired), ``compactions`` (re-gathers of
     the batch) and ``shapes`` (distinct ``(lanes, jobs, buckets)`` shapes
-    launched).
+    launched).  On a fabric with uplink domains it also returns, per
+    lane, the :data:`UPLINK_COUNTERS`, pulled as the lane retires.
 
     Each host phase sits in a profiler span (``jax.profiler.
     TraceAnnotation``, a no-op of about a microsecond when no trace is
     recorded): ``fluid.init``; per chunk, with the stat ``chunk``,
-    ``fluid.launch``, ``fluid.sync``, ``fluid.retire`` when lanes finish,
-    and ``fluid.compact`` when finished lanes are considered for
+    ``fluid.launch`` (with ``uplinks``, the fabric's uplink domains),
+    ``fluid.sync``, ``fluid.retire`` when lanes finish, and
+    ``fluid.compact`` when finished lanes are considered for
     compaction."""
     span = jax.profiler.TraceAnnotation
     with span("fluid.init"):
@@ -746,11 +789,14 @@ def _drive_batched(traces: Dict[str, jnp.ndarray], cfg: JaxSimConfig,
             traces["valid"] = jnp.ones((n_lanes0, n_jobs0), bool)
         state = _init_jit(traces, cfg)
     wfbp = "bucket_bytes" in traces and int(traces["bucket_bytes"].shape[-1]) > 1
+    lane_counters = [k for k in UPLINK_COUNTERS if k in state]
+    n_uplinks = int(_fabric(cfg).uplink_mask().sum())
     results = {
         "jct": np.full((n_lanes0, n_jobs0), np.inf, np.float32),
         "finished": np.zeros((n_lanes0, n_jobs0), bool),
         "makespan": np.zeros((n_lanes0,), np.float32),
         **dict.fromkeys(DRIVER_COUNTERS, 0),
+        **{k: np.zeros((n_lanes0,), np.int64) for k in lane_counters},
     }
     shapes = set()
     orig = np.arange(n_lanes0)  # current lane -> original row (-1 = retired)
@@ -763,7 +809,7 @@ def _drive_batched(traces: Dict[str, jnp.ndarray], cfg: JaxSimConfig,
         results["lane_slots"] += n_lanes_cur
         results["live_lane_slots"] += int((orig >= 0).sum())
         with span("fluid.launch", chunk=chunk, lanes=n_lanes_cur,
-                  jobs=n_jobs_cur):
+                  jobs=n_jobs_cur, uplinks=n_uplinks):
             state = _chunk_jit(traces, state, cfg, max_ways, gated)
         results["chunks"] += 1
         with span("fluid.sync", chunk=chunk):
@@ -773,11 +819,16 @@ def _drive_batched(traces: Dict[str, jnp.ndarray], cfg: JaxSimConfig,
             newly = [l for l in np.nonzero(done)[0] if orig[l] >= 0]
         if newly:
             with span("fluid.retire", chunk=chunk):
-                phase = np.asarray(state["phase"])
-                finish = np.asarray(state["finish"])
-                t_now = np.asarray(state["t"])
-                valid = np.asarray(traces["valid"])
-                arr = np.asarray(traces["arrival"], np.float32)
+                # one device_get, so that the pulls overlap rather than
+                # each waiting for the one before
+                pulled = jax.device_get({
+                    **{k: state[k] for k in ("phase", "finish", "t",
+                                             *lane_counters)},
+                    "valid": traces["valid"], "arrival": traces["arrival"],
+                })
+                phase, finish, t_now, valid, arr = (pulled[k] for k in (
+                    "phase", "finish", "t", "valid", "arrival"))
+                counts = {k: pulled[k] for k in lane_counters}
                 for l in newly:
                     row = orig[l]
                     fin = (phase[l] == DONE) & valid[l]
@@ -786,6 +837,8 @@ def _drive_batched(traces: Dict[str, jnp.ndarray], cfg: JaxSimConfig,
                     results["makespan"][row] = (
                         finish[l][fin].max() if fin.any() else t_now[l]
                     )
+                    for k, v in counts.items():
+                        results[k][row] = v[l]
                     orig[l] = -1
         if done.all():
             break
@@ -876,9 +929,10 @@ def simulate_trace(trace: Dict[str, jnp.ndarray], cfg: JaxSimConfig):
 def simulate_traces_batched(traces: Dict[str, jnp.ndarray], cfg: JaxSimConfig):
     """Chunked-scan launches over a stacked batch of traces (leading axis
     = seed; see :func:`stack_traces`).  Returns per-lane jct/finished
-    arrays, a per-lane makespan vector and the driver's query-wide
+    arrays, a per-lane makespan vector, the driver's query-wide
     counters (:data:`DRIVER_COUNTERS`: scan chunks launched, lane slots,
-    compactions, shapes) — the scenario Monte-Carlo entry point.
+    compactions, shapes) and, on a fabric with uplinks, the per-lane
+    :data:`UPLINK_COUNTERS` — the scenario Monte-Carlo entry point.
     Policy-dynamic like :func:`simulate_trace`; finished lanes retire
     between chunks (``cfg.compact``) so stragglers don't pay full batch
     width."""
@@ -890,7 +944,7 @@ def simulate_traces_batched(traces: Dict[str, jnp.ndarray], cfg: JaxSimConfig):
         "jct": jnp.asarray(out["jct"]),
         "finished": jnp.asarray(out["finished"]),
         "makespan": jnp.asarray(out["makespan"]),
-        **{k: out[k] for k in DRIVER_COUNTERS},
+        **{k: out[k] for k in DRIVER_COUNTERS + UPLINK_COUNTERS if k in out},
     }
 
 

@@ -135,6 +135,12 @@ class Topology:
     def oversub_array(self) -> np.ndarray:
         return np.asarray([d.oversub for d in self.domains], dtype=np.float32)
 
+    def uplink_mask(self) -> np.ndarray:
+        """``(n_domains,)`` bool: the uplink domains, those of more than
+        one server (a NIC domain holds one).  A ring that loads one
+        crosses a rack boundary."""
+        return np.asarray([len(d.servers) > 1 for d in self.domains], dtype=bool)
+
     # -- rack helpers for locality-aware placement -------------------------
     def rack_groups(self) -> Tuple[Tuple[int, ...], ...]:
         """Rack server groups; servers not assigned to any rack form one

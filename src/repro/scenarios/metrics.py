@@ -110,6 +110,14 @@ class RunMetrics:
     live_lane_slots: int = 0
     compactions: int = 0
     shapes: int = 0
+    #: fluid backend on a fabric with uplink domains, this rollout's own
+    #: (``jaxsim.UPLINK_COUNTERS``): jobs whose ring crossed a rack
+    #: boundary, and transfers in flight at each tick's end summed over
+    #: ticks, those over an uplink and all of them.  ``None`` where the
+    #: fabric has no uplink, and on the event backend.
+    uplink_jobs: Optional[int] = None
+    uplink_transfer_ticks: Optional[int] = None
+    transfer_ticks: Optional[int] = None
 
     def as_csv_row(self) -> str:
         vals = []
@@ -159,6 +167,9 @@ def from_jcts(
     live_lane_slots: int = 0,
     compactions: int = 0,
     shapes: int = 0,
+    uplink_jobs: Optional[int] = None,
+    uplink_transfer_ticks: Optional[int] = None,
+    transfer_ticks: Optional[int] = None,
 ) -> RunMetrics:
     jcts = [float(x) for x in jcts]
     n_fin = len(jcts)
@@ -195,6 +206,9 @@ def from_jcts(
         live_lane_slots=live_lane_slots,
         compactions=compactions,
         shapes=shapes,
+        uplink_jobs=uplink_jobs,
+        uplink_transfer_ticks=uplink_transfer_ticks,
+        transfer_ticks=transfer_ticks,
     )
 
 

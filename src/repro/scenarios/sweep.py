@@ -345,6 +345,7 @@ def monte_carlo_fluid(
 
     from repro.core.jaxsim import (
         DRIVER_COUNTERS,
+        UPLINK_COUNTERS,
         simulate_traces_batched,
         stack_traces,
         trace_from_jobs,
@@ -387,6 +388,7 @@ def monte_carlo_fluid(
                     "larger max_steps"
                 )
             counters = {k: out[k] for k in DRIVER_COUNTERS}
+            per_lane = {k: out[k] for k in UPLINK_COUNTERS if k in out}
             return [
                 metrics_mod.from_jcts(
                     jct[i][fin[i]].tolist(),
@@ -399,6 +401,7 @@ def monte_carlo_fluid(
                     makespan=float(mks[i]),
                     wall_s=wall,
                     **counters,
+                    **{k: int(v[i]) for k, v in per_lane.items()},
                 )
                 for i, (seed, scn) in enumerate(zip(seeds, scns))
             ]

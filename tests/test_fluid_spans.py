@@ -44,17 +44,14 @@ def answers(recs):
              r.p99_jct, r.makespan) for r in recs]
 
 
-@pytest.fixture(scope="module")
-def traced(tmp_path_factory):
-    """(records with the profiler off, records and program spans with
-    it on: (start, end, name, stats) of the thread that holds them)."""
+def trace_spans(tdir, query):
+    """``query()``'s records, and its program spans: (start, end, name,
+    stats) of the thread that holds them."""
     from jax.profiler import ProfileData
 
-    plain = run()
-    tdir = tmp_path_factory.mktemp("trace")
     jax.profiler.start_trace(str(tdir))
     try:
-        recs = run()
+        recs = query()
     finally:
         jax.profiler.stop_trace()
     (path,) = tdir.rglob("*.xplane.pb")
@@ -65,7 +62,15 @@ def traced(tmp_path_factory):
     ]
     threads = [t for t in threads if t]
     assert len(threads) == 1, "the spans lie on more than one thread"
-    return plain, recs, sorted(threads[0], key=lambda ev: (ev[0], -ev[1]))
+    return recs, sorted(threads[0], key=lambda ev: (ev[0], -ev[1]))
+
+
+@pytest.fixture(scope="module")
+def traced(tmp_path_factory):
+    """(records with the profiler off, records and program spans with
+    it on)."""
+    plain = run()
+    return (plain, *trace_spans(tmp_path_factory.mktemp("trace"), run))
 
 
 def parents(spans):
@@ -131,3 +136,14 @@ def test_no_compaction_one_shape():
     assert r.compactions == 0
     assert r.shapes == 1
     assert r.lane_slots == len(SEEDS) * r.chunks
+
+
+def test_launches_carry_the_fabric_uplinks(traced, tmp_path):
+    """``uplinks``: the fabric's uplink domains, none on ``paper``'s
+    NICs, one per rack of the two-tier ``oversub_fabric``."""
+    _, _, spans = traced
+    assert {st["uplinks"] for _, _, n, st in spans if n == "fluid.launch"} == {0}
+    _, spans = trace_spans(tmp_path, lambda: monte_carlo_fluid(
+        "oversub_fabric", SEEDS[:2], chunk_steps=CHUNK,
+        overrides={**SMALL, "n_servers": 8, "servers_per_rack": 2}))
+    assert {st["uplinks"] for _, _, n, st in spans if n == "fluid.launch"} == {4}

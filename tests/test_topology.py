@@ -13,10 +13,14 @@ Three layers of coverage:
   rack, and racks-of-one with oversub 1.0);
 * behavioural checks: oversubscribed uplinks slow cross-rack traffic on
   both backends, intra-rack traffic is unaffected, and the rack-aware
-  LWF placement keeps rack-sized jobs off the uplinks.
+  LWF placement keeps rack-sized jobs off the uplinks;
+* the fluid backend's uplink counters: kept only where the fabric has an
+  uplink domain, the same with and without the next-event skip, and
+  exact on a job whose transfers can be counted by hand.
 """
 
 import dataclasses
+import math
 
 import numpy as np
 import pytest
@@ -26,8 +30,14 @@ from repro.core.cluster import TABLE_III, Cluster, JobSpec
 from repro.core.contention import ContentionParams
 from repro.core.placement import PlacementPolicy, place_lwf_rack
 from repro.core.topology import Domain, Topology, nic_topology, two_tier, uplink_only
-from repro.scenarios import get_scenario, run_scenario_event, run_scenario_fluid
+from repro.scenarios import (
+    get_scenario,
+    monte_carlo_fluid,
+    run_scenario_event,
+    run_scenario_fluid,
+)
 from repro.scenarios.registry import Scenario
+from repro.scenarios.sweep import fluid_config
 
 
 class TestConstruction:
@@ -312,3 +322,73 @@ class TestRackAwarePlacement:
         rank = netmodel.rack_pack_rank(free, server_rack, 2, gpus_per_server=4)
         order = np.argsort(rank, kind="stable")
         assert order.tolist() == [2, 3, 0, 1]  # rack 1 (7 free) first, fuller-first inside
+
+
+class TestUplinkCounters:
+    """``jaxsim.UPLINK_COUNTERS``: admissions whose ring crosses a rack
+    boundary, and transfers in flight at each tick's end, over an uplink
+    and in all."""
+
+    SMALL = dict(n_jobs=24, min_iters=20, max_iters=80, horizon_s=30.0,
+                 n_servers=8, servers_per_rack=2)
+
+    @staticmethod
+    def counts(recs):
+        return [(r.uplink_jobs, r.uplink_transfer_ticks, r.transfer_ticks)
+                for r in recs]
+
+    @pytest.mark.parametrize("placement", ["lwf", "lwf_rack"])
+    def test_same_with_and_without_skip(self, placement):
+        on, off = (
+            self.counts(monte_carlo_fluid(
+                "oversub_fabric", [1, 2, 3, 4], placement=placement,
+                overrides=self.SMALL, skip=skip))
+            for skip in (True, False))
+        assert on == off
+        for jobs, uplink_ticks, ticks in on:
+            assert 1 <= jobs <= self.SMALL["n_jobs"]
+            assert 0 < uplink_ticks <= ticks
+
+    @pytest.mark.parametrize("n_gpus, crosses", [(3, True), (2, False)])
+    def test_one_job_counted_by_hand(self, n_gpus, crosses):
+        """Two racks of two one-GPU servers: a 3-GPU ring must cross the
+        racks and drains at k_eff = oversub 3; a 2-GPU one fills rack 0
+        under rack-aware placement and loads only NICs."""
+        from repro.core.jaxsim import simulate_traces_batched, stack_traces, trace_from_jobs
+
+        p, model, iters = ContentionParams(), TABLE_III["vgg16"], 10
+        scn = Scenario(
+            name="one", seed=0, n_servers=4, gpus_per_server=1,
+            jobs=(JobSpec(0, 0.0, n_gpus, iters, model),), params=p,
+            topology=two_tier(4, 2, oversub=3.0),
+        )
+        cfg = fluid_config(scn, placement="lwf_rack")
+        out = simulate_traces_batched(
+            stack_traces([trace_from_jobs(scn.job_list())]), cfg)
+        # a transfer's first tick drains dt at the contention it found
+        # (none: it is alone), each later one dt at the Eq. 5 fraction of
+        # k_eff; in flight at the end of every tick but its last
+        k = 3.0 if crosses else 1.0
+        comm = p.a + p.b * model.size_bytes
+        ticks = (comm - cfg.dt) / (cfg.dt * p.b / (k * p.b + (k - 1) * p.eta))
+        assert abs(ticks - round(ticks)) > 1e-3  # no end on a tick boundary
+        in_flight = iters * math.ceil(ticks)
+        assert out["uplink_jobs"].tolist() == [int(crosses)]
+        assert out["transfer_ticks"].tolist() == [in_flight]
+        assert out["uplink_transfer_ticks"].tolist() == [in_flight * crosses]
+
+    def test_nic_fabric_keeps_none(self):
+        """No uplink domain: no counter in the lane state (so the chunk
+        program is the one it was before they existed), and ``None`` in
+        every record."""
+        import jax
+
+        from repro.core.jaxsim import UPLINK_COUNTERS, _init_jit, stack_traces, trace_from_jobs
+
+        small = dict(n_jobs=8, min_iters=5, max_iters=20, horizon_s=10.0)
+        scn = get_scenario("paper", seed=0, **small)
+        batch = stack_traces([trace_from_jobs(scn.job_list())])
+        state = jax.eval_shape(lambda tr: _init_jit(tr, fluid_config(scn)), batch)
+        assert not set(UPLINK_COUNTERS) & set(state)
+        recs = monte_carlo_fluid("paper", [1, 2], overrides=small)
+        assert self.counts(recs) == [(None, None, None)] * 2
