@@ -89,7 +89,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
-from typing import Dict, List, Optional, Sequence, Tuple
+from typing import Dict, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -928,7 +928,10 @@ def simulate_trace(trace: Dict[str, jnp.ndarray], cfg: JaxSimConfig):
 
 def simulate_traces_batched(traces: Dict[str, jnp.ndarray], cfg: JaxSimConfig):
     """Chunked-scan launches over a stacked batch of traces (leading axis
-    = seed; see :func:`stack_traces`).  Returns per-lane jct/finished
+    = seed; see :func:`stack_traces`), as device arrays or host numpy
+    planes (moved to the device here, plane by plane: hand a host batch
+    to ``jax.device_put`` first for one overlapped transfer, as
+    ``scenarios.sweep.monte_carlo_fluid`` does).  Returns per-lane jct/finished
     arrays, a per-lane makespan vector, the driver's query-wide
     counters (:data:`DRIVER_COUNTERS`: scan chunks launched, lane slots,
     compactions, shapes) and, on a fabric with uplinks, the per-lane
@@ -948,22 +951,24 @@ def simulate_traces_batched(traces: Dict[str, jnp.ndarray], cfg: JaxSimConfig):
     }
 
 
-def trace_from_jobs(jobs, fusion: object = "all") -> Dict[str, jnp.ndarray]:
+def trace_from_jobs(jobs, fusion: object = "all") -> Dict[str, np.ndarray]:
     """Convert ``JobSpec`` lists (trace generator / scenario engine output)
-    into the struct-of-arrays layout the fluid simulator consumes.
+    into the struct-of-arrays layout the fluid simulator consumes: host
+    numpy planes (``float32`` ``arrival``/``iters``/``t_iter``/
+    ``msg_bytes``, ``int32`` ``n_gpus``), with no device transfer.
 
     ``fusion`` ('all' | 'none' | a byte threshold) adds the WFBP bucket
     planes: a static ``(jobs, B)`` ``bucket_bytes`` matrix (zero-padded)
-    plus per-job ``n_buckets``, from ``netmodel.fusion_plan`` over each
-    model's layer data.  Models without layer data (the paper's Table III
-    profiles) stay one monolithic bucket; ``fusion="all"`` omits the
-    planes entirely, which is bit-identical to the legacy trace."""
+    plus per-job ``int32`` ``n_buckets``, from ``netmodel.fusion_plan``
+    over each model's layer data.  Models without layer data (the paper's
+    Table III profiles) stay one monolithic bucket; ``fusion="all"`` omits
+    the planes entirely, which is bit-identical to the legacy trace."""
     tr = {
-        "arrival": jnp.asarray([j.arrival for j in jobs], jnp.float32),
-        "iters": jnp.asarray([j.iterations for j in jobs], jnp.float32),
-        "t_iter": jnp.asarray([j.model.t_iter_compute for j in jobs], jnp.float32),
-        "msg_bytes": jnp.asarray([j.model.size_bytes for j in jobs], jnp.float32),
-        "n_gpus": jnp.asarray([j.n_gpus for j in jobs], jnp.int32),
+        "arrival": np.array([j.arrival for j in jobs], np.float32),
+        "iters": np.array([j.iterations for j in jobs], np.float32),
+        "t_iter": np.array([j.model.t_iter_compute for j in jobs], np.float32),
+        "msg_bytes": np.array([j.model.size_bytes for j in jobs], np.float32),
+        "n_gpus": np.array([j.n_gpus for j in jobs], np.int32),
     }
     thr = netmodel.fusion_threshold(fusion)
     if thr == float("inf"):
@@ -979,50 +984,60 @@ def trace_from_jobs(jobs, fusion: object = "all") -> Dict[str, jnp.ndarray]:
     bb = np.zeros((len(plans), b_max), np.float32)
     for i, p in enumerate(plans):
         bb[i, : len(p)] = p
-    tr["bucket_bytes"] = jnp.asarray(bb)
-    tr["n_buckets"] = jnp.asarray([len(p) for p in plans], jnp.int32)
+    tr["bucket_bytes"] = bb
+    tr["n_buckets"] = np.array([len(p) for p in plans], np.int32)
     return tr
 
 
-def stack_traces(traces: Sequence[Dict[str, jnp.ndarray]]) -> Dict[str, jnp.ndarray]:
+#: each batch plane's dtype and the value that pads it with inert jobs
+_PLANES = {
+    "arrival": (np.float32, 0.0),
+    "iters": (np.float32, 1.0),
+    "t_iter": (np.float32, 1.0),
+    "msg_bytes": (np.float32, 0.0),
+    "n_gpus": (np.int32, 1),
+    "valid": (np.bool_, False),
+    "bucket_bytes": (np.float32, 0.0),
+    "n_buckets": (np.int32, 1),
+}
+
+
+def stack_traces(traces: Sequence[Dict[str, np.ndarray]]) -> Dict[str, np.ndarray]:
     """Stack per-seed traces into one rectangular batch for
     :func:`simulate_traces_batched`, padding ragged job counts with inert
     jobs masked out by a boolean ``valid`` plane (padded lanes start DONE
     and are excluded from ``finished``).  WFBP bucket planes
     (``bucket_bytes``/``n_buckets``, see :func:`trace_from_jobs`) are
     padded along both the job and the bucket axis; lanes missing the
-    planes get monolithic ones when any lane carries them."""
+    planes get monolithic ones when any lane carries them.
+
+    Each plane is allocated once on the host, filled with its pad, and
+    each lane's rows are copied in; lanes may be numpy or jax arrays.
+    Returns numpy planes and runs no device program: the caller makes
+    the one transfer (``jax.device_put`` of the whole dict)."""
     if not traces:
         raise ValueError("need at least one trace to stack")
-    n_max = max(int(tr["arrival"].shape[0]) for tr in traces)
-    has_buckets = any("bucket_bytes" in tr for tr in traces)
+    lanes = [{k: np.asarray(v) for k, v in tr.items()} for tr in traces]
+    n_max = max(lane["arrival"].shape[0] for lane in lanes)
+    has_buckets = any("bucket_bytes" in lane for lane in lanes)
     b_max = max(
-        (int(tr["bucket_bytes"].shape[-1]) for tr in traces if "bucket_bytes" in tr),
+        (lane["bucket_bytes"].shape[-1] for lane in lanes if "bucket_bytes" in lane),
         default=1,
     )
-
-    def pad(x, fill):
-        pad_n = n_max - x.shape[0]
-        if x.ndim == 2:  # (jobs, buckets): zero-fill both axes
-            return jnp.pad(
-                x, ((0, pad_n), (0, b_max - x.shape[1])), constant_values=fill
-            )
-        return jnp.concatenate([x, jnp.full((pad_n,), fill, x.dtype)])
-
-    out: Dict[str, List[jnp.ndarray]] = {}
-    for tr in traces:
-        n = int(tr["arrival"].shape[0])
-        lane = dict(tr)
-        lane.setdefault("valid", jnp.ones((n,), bool))
+    out = {}
+    for k in sorted(set().union(*lanes, ["valid"])):
+        dtype, fill = _PLANES[k]
+        shape = (len(lanes), n_max) + ((b_max,) if k == "bucket_bytes" else ())
+        out[k] = np.full(shape, fill, dtype)
+    for i, lane in enumerate(lanes):
+        n = lane["arrival"].shape[0]
+        lane.setdefault("valid", np.ones((n,), bool))
         if has_buckets and "bucket_bytes" not in lane:
             lane["bucket_bytes"] = lane["msg_bytes"][:, None]
-            lane["n_buckets"] = jnp.ones((n,), jnp.int32)
-        fills = {"arrival": 0.0, "iters": 1.0, "t_iter": 1.0,
-                 "msg_bytes": 0.0, "n_gpus": 1, "valid": False,
-                 "bucket_bytes": 0.0, "n_buckets": 1}
+            lane["n_buckets"] = np.ones((n,), np.int32)
         for k, v in lane.items():
-            out.setdefault(k, []).append(pad(v, fills[k]))
-    return {k: jnp.stack(vs) for k, vs in out.items()}
+            out[k][(i,) + tuple(slice(d) for d in v.shape)] = v
+    return out
 
 
 def simulate_jobs(jobs, cfg: JaxSimConfig, fusion: object = "all") -> Dict[str, np.ndarray]:

@@ -324,11 +324,16 @@ def monte_carlo_fluid(
     **fast_kw,
 ) -> List[metrics_mod.RunMetrics]:
     """All seeds of one scenario x policy x placement cell in ONE vmapped
-    fluid launch: per-seed traces are padded/stacked
-    (``jaxsim.stack_traces``) and swept by ``simulate_traces_batched`` —
-    one XLA compilation, one device launch, one :class:`RunMetrics` per
-    seed.  The contention model/cluster shape must not vary with the seed
-    (true for every registered scenario); the seed only resamples jobs.
+    fluid launch: per-seed traces are encoded and padded/stacked on the
+    host as numpy planes (``jaxsim.trace_from_jobs``,
+    ``jaxsim.stack_traces``), moved to the device in one
+    ``jax.device_put`` of the whole batch, and swept by
+    ``simulate_traces_batched`` — one XLA compilation, one
+    :class:`RunMetrics` per seed.  The ``fluid.build.stack`` span carries
+    what the transfer moved: ``lanes``, ``jobs`` (the padded width) and
+    ``bytes``.  The contention model/cluster shape must not vary with the
+    seed (true for every registered scenario); the seed only resamples
+    jobs.
 
     Stacking pads every seed's trace to the batch-max job count, but the
     padding does NOT persist for the whole run: the chunked driver re-pads
@@ -340,6 +345,7 @@ def monte_carlo_fluid(
     Raises ``RuntimeError`` if any lane reaches the horizon cap
     (``max_steps`` ticks) with jobs unfinished: its JCT statistics would
     cover only the jobs that finished and read as a fast run."""
+    import jax
     import numpy as np
     from jax.profiler import TraceAnnotation as span
 
@@ -365,9 +371,12 @@ def monte_carlo_fluid(
             with span("fluid.build.encode"):
                 traces = [trace_from_jobs(s.job_list(), fusion=s.fusion)
                           for s in scns]
-            with span("fluid.build.stack"):
-                batch = stack_traces(traces)
-            del traces  # else every lane's arrays stay on the device
+            with span("fluid.build.stack") as stack:
+                host = stack_traces(traces)
+                stack.set_metadata(
+                    lanes=len(seeds), jobs=host["arrival"].shape[1],
+                    bytes=sum(v.nbytes for v in host.values()))
+                batch = jax.device_put(host)
         out = simulate_traces_batched(batch, cfg)
         with span("fluid.collect"):
             jct = np.asarray(out["jct"])

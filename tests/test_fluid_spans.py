@@ -98,6 +98,22 @@ def test_every_span_nests_as_listed(traced):
     assert query["lanes"] == len(SEEDS)
 
 
+def test_stack_span_carries_the_transfer(traced):
+    """``fluid.build.stack``: the lanes, padded job width and bytes of
+    the one host-to-device transfer of the batch."""
+    from repro.core.jaxsim import stack_traces, trace_from_jobs
+    from repro.scenarios import get_scenario
+
+    _, _, spans = traced
+    (st,) = [st for _, _, n, st in spans if n == "fluid.build.stack"]
+    batch = stack_traces([
+        trace_from_jobs(s.job_list(), fusion=s.fusion)
+        for s in (get_scenario("paper", seed=seed, **SMALL) for seed in SEEDS)])
+    assert st["lanes"] == len(SEEDS)
+    assert st["jobs"] == batch["arrival"].shape[1] == SMALL["n_jobs"]
+    assert st["bytes"] == sum(v.nbytes for v in batch.values())
+
+
 def test_launches_carry_their_chunk(traced):
     _, recs, spans = traced
     launches = [st for _, _, n, st in spans if n == "fluid.launch"]
