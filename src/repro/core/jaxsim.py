@@ -1,88 +1,79 @@
-"""Vectorized JAX cluster simulator — Monte-Carlo over traces in one jit.
+"""Vectorized JAX cluster simulator: Monte-Carlo over traces in one jit.
 
-Beyond-paper extension #3 (DESIGN.md §7): a fixed-timestep, fully-batched
-("fluid") approximation of the Ada-SRSF dynamics in pure ``jax.lax``
-control flow, ``vmap``-able over seeds, so JCT confidence intervals over
-dozens of sampled workloads cost one XLA compilation and one device launch.
+A fixed-timestep, batched ("fluid") approximation of the Ada-SRSF
+dynamics in ``jax.lax`` control flow, ``vmap``-ed over seeded traces, so
+JCT confidence intervals over many sampled workloads cost one compiled
+program per trace shape.
 
 The policy/network math (Eq. 5 rate model, per-server bandwidth, gating
 predicates, placement ranking) lives in ``core/netmodel.py`` and is shared
-with the exact event simulator; this module only supplies the fluid state
-machine around it.  Feature parity with the event backend:
+with the exact event simulator; this module supplies the fluid state
+machine around it:
 
 * every gating policy: AdaDUAL, SRSF(n), and k-way AdaDUAL (``kway2``/
-  ``kway3``/...) — k-way runs the *exact* per-bucket lookahead
+  ``kway3``/...), which runs the exact per-bucket lookahead
   (``netmodel.kway_exact_start``);
 * per-server heterogeneous NIC bandwidth (slowest-member drain rate);
 * fabric contention domains (``core/topology.py``) via a static
   ``[domains, servers]`` incidence matrix;
-* pluggable gang placement: ``consolidate`` / ``first_fit`` /
-  ``least_loaded`` / ``random`` / ``rack_pack``.
+* gang placement: ``consolidate`` / ``first_fit`` / ``least_loaded`` /
+  ``random`` / ``rack_pack``.
 
-Fast-path architecture (the raw-speed program)
-----------------------------------------------
+One step, one driver
+--------------------
 
-The hot loop is no longer one monolithic ``lax.while_loop`` over fixed dt
-ticks.  It is a *segmented* driver:
+* **The step** (:func:`_make_lane_step`) executes one tick: admission and
+  placement, one evaluation of the contention/rate core
+  (:func:`fluid_step_core`), the compute drain, gating, the comm drain and
+  the iteration bookkeeping.  Monolithic traces start at most one gated
+  all-reduce per tick (smallest remaining service first); bucketed WFBP
+  traces start the greedy gating closure of
+  :func:`netmodel.gating_fixed_point` in one masked pass.
 
-* **Chunked scan** — lanes advance through ``cfg.chunk_steps``-step
-  ``lax.scan`` segments (one jitted launch per segment); finished lanes
-  freeze via a per-lane ``live`` guard.  Between segments the host checks
-  for all-lanes-done early exit and (``cfg.compact``) retires finished
-  lanes, shrinks the lane axis to the next power of two, and trims
-  trailing all-invalid job columns (multiples of 8) and dead bucket
-  columns.  Compaction is bit-exact: lanes are computationally
-  independent, and padded jobs are inert in every reduction (zero member
-  rows, ``inf`` priority keys, ``x + 0.0`` exact in any order).
+* **Next-event skip** (``cfg.skip``): after the executed tick the step
+  computes, per lane, how many following ticks are eventless (pure linear
+  drains: no admission, no phase transition, no gating decision that could
+  flip) and advances the drains in bulk.  Skipping gating re-evaluations is
+  safe because the threshold predicate is antitone in the active set and
+  non-increasing in time while the set is fixed (see
+  :func:`netmodel.gating_fixed_point`); exact k-way is a cost comparison,
+  not a monotone threshold, so the skip is off while any transfer waits
+  under it.  Bulk advancement computes ``rem - n*dt`` instead of n
+  subtractions, so a skip run may drift from a tick-by-tick run by ulps
+  (at most one tick per phase segment); runs with the *same* config are
+  bit-exact across batching, padding and compaction.
 
-* **Next-event skip** (``cfg.skip``) — each executed tick is the exact
-  legacy tick; afterwards the step computes, per lane, how many following
-  ticks are *eventless* (pure linear drains: no admission, no phase
-  transition, no gating re-evaluation that could flip) and advances the
-  drains in bulk.  Safety of skipping gating re-evaluations follows from
-  the threshold predicate being antitone in the active set and monotone
-  (non-increasing) in time while the active set is fixed — see
-  :func:`netmodel.gating_fixed_point`; exact-lookahead k-way policies are
-  a cost *comparison*, not a monotone threshold, so the skip is disabled
-  while any transfer waits under exact k-way.  Bulk advancement computes
-  remainders as ``rem - n*dt`` instead of n sequential subtractions, so a
-  skip run may drift from a tick-by-tick run by ulps (≤ one tick per
-  phase segment) — within the differential-harness tolerances; runs with
-  the *same* config remain bit-exact across batching, padding and
-  compaction.
+* **The driver** (:func:`_drive_batched`, entered through
+  :func:`simulate_traces_batched`) runs lanes through
+  ``cfg.chunk_steps``-tick ``lax.scan`` segments, one jitted launch each;
+  finished lanes freeze via a per-lane ``live`` guard.  Between segments
+  the host exits once every lane is done and (``cfg.compact``) retires
+  finished lanes, shrinks the lane axis to the next power of two, and
+  trims trailing all-invalid job columns (multiples of 8) and dead bucket
+  columns.  Compaction is bit-exact: lanes are independent, and padded
+  jobs are inert in every reduction.
 
-* **One-shot gating fixed point** — bucketed WFBP traces used to run four
-  sequential gating rounds per tick; ``cfg.gating="fixedpoint"`` computes
-  the greedy closure in a single masked pass
-  (:func:`netmodel.gating_fixed_point`), ``"rounds"`` keeps the legacy
-  loop (equivalence locked in tests/test_fastpath.py).
+``skip=False`` and ``compact=False`` are the tick-by-tick and
+uncompacted references the tests hold the fast settings to.
 
-* **Fused step core** — the per-tick contention/rate evaluation (domain
-  incidence matmuls, Eq. 5 rate, slowest-member scale, gating-side
-  ``k_would``/``min_old_rem``) is one call into
-  ``repro.kernels.fluidstep``, the same lax code on every backend.
+Remaining approximations against the event simulator, each documented and
+tested for qualitative agreement:
 
-Remaining approximations vs the event simulator (``core/simulator.py``),
-all documented and tested for *qualitative* agreement:
-
-* gang placement — a job occupies whole GPUs exclusively;
+* gang placement: a job occupies whole GPUs exclusively;
 * time advances in fixed dt steps; compute/comm remainders drain linearly
-  (the Eq. 5 rate model is exact within a step as long as the active comm
-  set is unchanged, so dt only quantizes *transition* times);
-* at most one queued job is admitted per step and (monolithic traces) one
-  gated all-reduce started per step; bucketed WFBP traces start the full
-  gating closure per step instead;
-* WFBP tensor-fusion buckets drain as a chunked FIFO stream over a static
+  (the Eq. 5 rate is exact within a step while the active comm set is
+  unchanged, so dt only quantizes transition times);
+* at most one queued job is admitted per step;
+* WFBP tensor-fusion buckets drain as a FIFO stream over a static
   ``[jobs, buckets]`` size matrix; overlap of transfers with remaining
-  backward compute is NOT modeled (documented pessimism);
+  backward compute is not modeled (documented pessimism);
 * the fixed all-reduce latency ``a`` is folded into the bandwidth term
   (under WFBP it is charged once per bucket).
 
 State is a struct-of-arrays over jobs plus per-server occupancy; policies
-are branchless masks parameterized by the shared layer.  Traces may carry
-a boolean ``valid`` mask so ragged per-seed traces can be padded to one
-rectangular batch (see :func:`stack_traces`) and swept in a single
-``vmap`` (:func:`simulate_traces_batched`).
+are branchless masks parameterized by the shared layer.  Traces carry a
+boolean ``valid`` mask so ragged per-seed traces pad to one rectangular
+batch (:func:`stack_traces`).
 """
 
 from __future__ import annotations
@@ -96,11 +87,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core import netmodel
-from repro.core.cluster import TABLE_III
 from repro.core.contention import ContentionParams
 from repro.core.topology import Topology, nic_topology
-from repro.core.trace import PAPER_GPU_DISTRIBUTION
-from repro.kernels import fluidstep
 
 # job phases
 QUEUED, COMPUTE, COMM, DONE = 0, 1, 2, 3
@@ -147,7 +135,7 @@ class JaxSimConfig:
     #: servers beyond the tuple are nominal, () = homogeneous network.
     server_bandwidth: Tuple[float, ...] = ()
     #: fabric contention domains (core/topology.py); None = the paper's
-    #: NIC-only model (bit-identical to pre-topology behaviour).  Topology
+    #: NIC-only model (one NIC domain per server).  Topology
     #: is frozen/hashable, so it rides along as part of this jit-static
     #: config and lowers to *static* incidence/oversub matrices.
     topology: Optional[Topology] = None
@@ -157,51 +145,14 @@ class JaxSimConfig:
     #: ticks per jitted scan segment between host early-exit/compaction
     #: checks.
     chunk_steps: int = 256
-    #: WFBP per-tick re-gating: "fixedpoint" (one-shot greedy closure) or
-    #: "rounds" (legacy 4-round loop); monolithic traces always use the
-    #: single legacy round.
-    gating: str = "fixedpoint"
     #: bulk-advance eventless ticks (next-event skip).
     skip: bool = True
     #: retire finished lanes / trim padding between chunks.
     compact: bool = True
 
     def __post_init__(self) -> None:
-        if self.gating not in ("fixedpoint", "rounds"):
-            raise ValueError(
-                f"unknown gating mode {self.gating!r}: expected "
-                "'fixedpoint' or 'rounds'"
-            )
         if self.chunk_steps < 1:
             raise ValueError(f"chunk_steps must be >= 1, got {self.chunk_steps}")
-
-
-def sample_trace(key, n_jobs: int, horizon: float = 1200.0,
-                 min_iters: int = 1000, max_iters: int = 6000) -> Dict[str, jnp.ndarray]:
-    """Paper-distribution workload as arrays (vmap-able over keys)."""
-    models = list(TABLE_III.values())
-    t_iter = jnp.asarray([m.t_iter_compute for m in models])
-    sizes = jnp.asarray([m.size_bytes for m in models])
-
-    gpu_choices, probs = [], []
-    total = sum(c for _, c in PAPER_GPU_DISTRIBUTION)
-    for g, c in PAPER_GPU_DISTRIBUTION:
-        gpu_choices.append(g)
-        probs.append(c / total)
-    k1, k2, k3, k4 = jax.random.split(key, 4)
-    arrival = jnp.floor(jax.random.uniform(k1, (n_jobs,), minval=1.0, maxval=horizon))
-    iters = jax.random.randint(k2, (n_jobs,), min_iters, max_iters + 1)
-    midx = jax.random.randint(k3, (n_jobs,), 0, len(models))
-    gidx = jax.random.choice(
-        k4, jnp.asarray(gpu_choices), (n_jobs,), p=jnp.asarray(probs)
-    )
-    return {
-        "arrival": arrival,
-        "iters": iters.astype(jnp.float32),
-        "t_iter": t_iter[midx],
-        "msg_bytes": sizes[midx],
-        "n_gpus": gidx.astype(jnp.int32),
-    }
 
 
 def _place(free: jnp.ndarray, n_gpus: jnp.ndarray,
@@ -268,20 +219,23 @@ def _fabric(cfg: JaxSimConfig) -> Topology:
     return nic_topology(cfg.n_servers)
 
 
+def _is_wfbp(trace) -> bool:
+    """Whether a trace (or batch) carries multi-bucket WFBP planes: a
+    compile-time flag, since ``(jobs, 1)`` planes and none at all run the
+    monolithic step."""
+    bucket_bytes = trace.get("bucket_bytes")
+    return bucket_bytes is not None and int(bucket_bytes.shape[-1]) > 1
+
+
 def _init_lane_state(trace: Dict[str, jnp.ndarray], cfg: JaxSimConfig):
-    """Initial per-lane state (legacy layout + the tick counter ``i``,
-    and the :data:`UPLINK_COUNTERS` where the fabric has an uplink)."""
+    """Initial per-lane state, with the tick counter ``i`` and the
+    :data:`UPLINK_COUNTERS` where the fabric has an uplink."""
     n_jobs = trace["arrival"].shape[0]
     ns = cfg.n_servers
-    valid = trace.get("valid")
-    if valid is None:
-        valid = jnp.ones((n_jobs,), bool)
-    bucket_bytes = trace.get("bucket_bytes")
-    wfbp = bucket_bytes is not None and int(bucket_bytes.shape[-1]) > 1
     topo = _fabric(cfg)
     n_domains = np.asarray(topo.incidence()).shape[0]
     state = {
-        "phase": jnp.where(valid, QUEUED, DONE).astype(jnp.int32),
+        "phase": jnp.where(trace["valid"], QUEUED, DONE).astype(jnp.int32),
         # domain-load mask, maintained incrementally (membership only
         # changes at admission / completion) so the hot loop never
         # re-derives it via incidence matmuls
@@ -296,16 +250,69 @@ def _init_lane_state(trace: Dict[str, jnp.ndarray], cfg: JaxSimConfig):
         "i": jnp.asarray(0, jnp.int32),
         "started": jnp.zeros((n_jobs,), bool),
     }
-    if wfbp:
+    if _is_wfbp(trace):
         state["bucket"] = jnp.zeros((n_jobs,), jnp.int32)
     if topo.uplink_mask().any():
         state.update(dict.fromkeys(UPLINK_COUNTERS, jnp.asarray(0, jnp.int32)))
     return state
 
 
+def fluid_step_core(loads, member, active, rem, bw, oversub, *,
+                    b: float, eta: float, need_overlap: bool = False):
+    """One tick's contention/rate evaluation.
+
+    ``loads`` is carried in the lane state rather than derived here: it
+    changes only at admission and completion.  ``min_old_rem`` is a min of
+    per-domain minima rather than a masked min over the ``(J, J)`` overlap
+    matrix; f32 ``min`` is exact, so both forms give the same bits, and
+    this one needs no ``J x J`` intermediate.
+
+    Args:
+      loads: ``(J, D)`` bool, the contention domains each job's ring
+        crosses (``netmodel.domain_loads``).
+      member: ``(J, S)`` float {0,1}, the servers each job holds GPUs on.
+      active: ``(J,)`` bool, transfers in flight (started, ``rem > 0``).
+      rem: ``(J,)`` float, remaining cost of each job's current phase.
+      bw: ``(S,)`` float, per-server relative NIC bandwidth.
+      oversub: ``(D,)`` float, per-domain oversubscription.
+      b / eta: Eq. 5 per-byte cost and contention penalty (static).
+      need_overlap: build the ``(J, J)`` overlap matrix (WFBP and exact
+        k-way gating need it; the monolithic threshold path does not).
+
+    Returns a dict with ``counts`` (D, int32), ``k_eff`` (J, float),
+    ``ratio`` (J, float: the slowest-member-scaled Eq. 5 rate fraction),
+    ``k_would`` (J, int32), ``min_old_rem`` (J, float, ``inf`` where no
+    overlapping transfer is in flight) and ``overlap`` (``(J, J)`` bool,
+    or None unless ``need_overlap``).
+    """
+    counts = netmodel.domain_counts(loads, active)  # (D,)
+    k_eff = netmodel.domain_k(loads, counts.astype(jnp.float32) * oversub)
+    scale = netmodel.slowest_member_scale(bw, member > 0)
+    ratio = scale * netmodel.rate_ratio(k_eff, b, eta)
+    k_would = netmodel.domain_k(loads, counts, extra=1)
+    # per-domain minimum in-flight remainder, then min over loaded domains
+    dmin = jnp.where(loads & active[:, None], rem[:, None], jnp.inf).min(axis=0)
+    min_old_rem = jnp.where(loads, dmin[None, :], jnp.inf).min(axis=1)
+    return {
+        "counts": counts,
+        "k_eff": k_eff,
+        "ratio": ratio,
+        "k_would": k_would,
+        "min_old_rem": min_old_rem,
+        "overlap": job_overlap(loads) if need_overlap else None,
+    }
+
+
+def job_overlap(loads):
+    """``(J, J)`` bool: which jobs load a common domain (an exact product
+    of {0,1} values compared with 0)."""
+    loads_f = loads.astype(jnp.float32)
+    return (loads_f @ loads_f.T) > 0
+
+
 def _make_lane_step(trace: Dict[str, jnp.ndarray], cfg: JaxSimConfig,
                     max_ways, gated):
-    """Build the per-lane step function: one *legacy-exact* tick followed
+    """Build the per-lane step function: one executed tick followed
     (``cfg.skip``) by the bulk advancement of eventless ticks."""
     n_jobs = trace["arrival"].shape[0]
     ns = cfg.n_servers
@@ -340,13 +347,13 @@ def _make_lane_step(trace: Dict[str, jnp.ndarray], cfg: JaxSimConfig,
 
     # WFBP tensor-fusion buckets (layer-granular comm subsystem): a static
     # ``(jobs, B)`` size matrix plus a per-job bucket count.  ``wfbp`` is a
-    # COMPILE-TIME flag: without multi-bucket planes (fusion="all" / legacy
-    # traces, and (jobs, 1) planes) the emitted graph is exactly the
-    # pre-bucket backend's (regression-locked in tests/test_wfbp.py).
-    bucket_bytes = trace.get("bucket_bytes")
-    b_max = 1 if bucket_bytes is None else int(bucket_bytes.shape[-1])
-    wfbp = b_max > 1
+    # compile-time flag: without multi-bucket planes (fusion="all", or
+    # (jobs, 1) planes) the step is the monolithic one (locked in
+    # tests/test_wfbp.py).
+    wfbp = _is_wfbp(trace)
     if wfbp:
+        bucket_bytes = trace["bucket_bytes"]
+        b_max = int(bucket_bytes.shape[-1])
         n_buckets = trace["n_buckets"].astype(jnp.int32)
         # per-bucket contention-free seconds; the latency `a` is paid per
         # bucket (the real cost of finer granularity), folded into the drain
@@ -450,15 +457,13 @@ def _make_lane_step(trace: Dict[str, jnp.ndarray], cfg: JaxSimConfig,
         # would otherwise see itself and deadlock under ada/srsf1).
         active = in_comm & started & (rem > 0)
         member = (servers > 0).astype(jnp.float32)  # (jobs, ns)
-        # ONE fused evaluation of the contention/rate core: in-flight
-        # counts over the carried domain-load mask, oversub-weighted
-        # effective k, Eq. 5 drain ratio, and the gating-side k_would /
-        # min_old_rem (+ the overlap matrix where gating needs it)
-        # (repro.kernels.fluidstep).  Evaluated pre-compute-drain:
-        # min_old_rem/k_would only read COMM rows, whose ``rem`` the
-        # compute drain below cannot touch — bit-exact with the legacy
-        # post-drain evaluation.
-        core = fluidstep.fluid_step_core(
+        # One evaluation of the contention/rate core: in-flight counts over
+        # the carried domain-load mask, oversub-weighted effective k, Eq. 5
+        # drain ratio, and the gating-side k_would / min_old_rem (+ the
+        # overlap matrix where gating needs it).  Evaluated before the
+        # compute drain: min_old_rem/k_would only read COMM rows, whose
+        # ``rem`` the compute drain below cannot touch.
+        core = fluid_step_core(
             loads, member, active, rem, bw, oversub,
             b=cfg.b, eta=cfg.eta,
             need_overlap=(wfbp or exact_kway),
@@ -500,15 +505,15 @@ def _make_lane_step(trace: Dict[str, jnp.ndarray], cfg: JaxSimConfig,
                 cfg.dual_threshold,
             )
 
-        # round 1 against the base active set, reusing the core outputs
-        # (the exact legacy contention state)
+        # candidates passing against the base active set
         olds0 = overlap & active[None, :] if overlap is not None else None
         start_ok = waiting & may_start_vs(
             core["k_would"], core["min_old_rem"], olds0
         )
-        if wfbp and cfg.gating == "fixedpoint":
-            # One-shot greedy closure (see netmodel.gating_fixed_point for
-            # the antitone-predicate argument); replaces the 4-round loop.
+        if wfbp:
+            # the greedy gating closure in one masked pass (see
+            # netmodel.gating_fixed_point for the antitone-predicate
+            # argument)
             accept = netmodel.gating_fixed_point(
                 start_ok, rem_service, loads, counts, overlap, active, rem,
                 new_cost, max_ways, gated, cfg.dual_threshold,
@@ -517,34 +522,14 @@ def _make_lane_step(trace: Dict[str, jnp.ndarray], cfg: JaxSimConfig,
             started = started | accept
             leftover = start_ok & ~accept
         else:
-            # Legacy single-start round: smallest remaining service first —
-            # mirrors the event sim's sorted re-evaluate-after-each-start
-            # loop (admissions/starts are rare relative to dt for
-            # monolithic traces, so one start per tick rarely binds).
+            # one start a tick, smallest remaining service first: the event
+            # sim's sorted re-evaluate-after-each-start loop (starts are
+            # rare relative to dt for monolithic traces, so the cap rarely
+            # binds)
             pick_c = jnp.argmin(jnp.where(start_ok, rem_service, jnp.inf))
             start_now = (jnp.arange(n_jobs) == pick_c) & start_ok
             started = started | start_now
             leftover = start_ok & ~start_now
-            if wfbp:
-                # legacy 4-round loop (cfg.gating == "rounds"): each extra
-                # round refreshes the contention state including the jobs
-                # started in earlier rounds and starts one more candidate.
-                for _ in range(3):
-                    active_now = in_comm & started & (rem > 0)
-                    counts_now = netmodel.domain_counts(loads, active_now)
-                    k_would = netmodel.domain_k(loads, counts_now, extra=1)
-                    min_old_rem = jnp.where(
-                        overlap & active_now[None, :], rem[None, :], jnp.inf
-                    ).min(axis=1)
-                    ok = (in_comm & ~started) & may_start_vs(
-                        k_would, min_old_rem, overlap & active_now[None, :]
-                    )
-                    pick_c = jnp.argmin(jnp.where(ok, rem_service, jnp.inf))
-                    started = started | ((jnp.arange(n_jobs) == pick_c) & ok)
-                # conservative skip guard for the legacy path: any waiter
-                # blocks bulk advancement (the closure membership is not
-                # re-derived here)
-                leftover = in_comm & ~started
 
         # ---- drain comm (started only), at the Eq. 5 rate evaluated at the
         # effective (oversub-weighted) contention and scaled by the slowest
@@ -627,7 +612,7 @@ def _make_lane_step(trace: Dict[str, jnp.ndarray], cfg: JaxSimConfig,
             return count_transfers(new_state, 1)
 
         # ---- next-event skip: bulk-advance eventless ticks ------------------
-        # An executed tick is exactly the legacy tick above; ``extra`` is a
+        # The executed tick is the one above; ``extra`` is a
         # per-lane lower bound on the number of *following* ticks at which
         # provably nothing discrete happens — no admission, no compute/comm
         # completion, no gating decision that could flip (the threshold
@@ -788,7 +773,7 @@ def _drive_batched(traces: Dict[str, jnp.ndarray], cfg: JaxSimConfig,
             traces = dict(traces)
             traces["valid"] = jnp.ones((n_lanes0, n_jobs0), bool)
         state = _init_jit(traces, cfg)
-    wfbp = "bucket_bytes" in traces and int(traces["bucket_bytes"].shape[-1]) > 1
+    wfbp = _is_wfbp(traces)
     lane_counters = [k for k in UPLINK_COUNTERS if k in state]
     n_uplinks = int(_fabric(cfg).uplink_mask().sum())
     results = {
@@ -900,32 +885,6 @@ def _drive_batched(traces: Dict[str, jnp.ndarray], cfg: JaxSimConfig,
     return results
 
 
-def simulate_one(key, n_jobs: int, cfg: JaxSimConfig):
-    trace = _sample_trace_jit(key, n_jobs)
-    return simulate_trace(trace, cfg)
-
-
-@functools.partial(jax.jit, static_argnames=("n_jobs",))
-def _sample_trace_jit(key, n_jobs: int):
-    return sample_trace(key, n_jobs)
-
-
-def simulate_trace(trace: Dict[str, jnp.ndarray], cfg: JaxSimConfig):
-    """Fluid-simulate a *fixed* workload (scenario-engine entry point).
-
-    The gating policy enters the jitted graph as runtime scalars
-    (:func:`_policy_args`), so sweeping policies over one trace shape
-    reuses a single XLA compilation."""
-    max_ways, gated, cfg_key = _policy_args(cfg)
-    batch = {k: jnp.asarray(v)[None] for k, v in trace.items()}
-    out = _drive_batched(batch, cfg_key, max_ways, gated)
-    return {
-        "jct": jnp.asarray(out["jct"][0]),
-        "finished": jnp.asarray(out["finished"][0]),
-        "makespan": jnp.asarray(out["makespan"][0]),
-    }
-
-
 def simulate_traces_batched(traces: Dict[str, jnp.ndarray], cfg: JaxSimConfig):
     """Chunked-scan launches over a stacked batch of traces (leading axis
     = seed; see :func:`stack_traces`), as device arrays or host numpy
@@ -935,10 +894,10 @@ def simulate_traces_batched(traces: Dict[str, jnp.ndarray], cfg: JaxSimConfig):
     arrays, a per-lane makespan vector, the driver's query-wide
     counters (:data:`DRIVER_COUNTERS`: scan chunks launched, lane slots,
     compactions, shapes) and, on a fabric with uplinks, the per-lane
-    :data:`UPLINK_COUNTERS` — the scenario Monte-Carlo entry point.
-    Policy-dynamic like :func:`simulate_trace`; finished lanes retire
-    between chunks (``cfg.compact``) so stragglers don't pay full batch
-    width."""
+    :data:`UPLINK_COUNTERS`.  The gating policy enters the jitted graph
+    as runtime scalars (:func:`_policy_args`), so sweeping policies over
+    one trace shape reuses one compilation; finished lanes retire between
+    chunks (``cfg.compact``) so stragglers don't pay full batch width."""
     max_ways, gated, cfg_key = _policy_args(cfg)
     out = _drive_batched(
         {k: jnp.asarray(v) for k, v in traces.items()}, cfg_key, max_ways, gated
@@ -962,7 +921,7 @@ def trace_from_jobs(jobs, fusion: object = "all") -> Dict[str, np.ndarray]:
     plus per-job ``int32`` ``n_buckets``, from ``netmodel.fusion_plan``
     over each model's layer data.  Models without layer data (the paper's
     Table III profiles) stay one monolithic bucket; ``fusion="all"`` omits
-    the planes entirely, which is bit-identical to the legacy trace."""
+    the planes entirely, the monolithic trace."""
     tr = {
         "arrival": np.array([j.arrival for j in jobs], np.float32),
         "iters": np.array([j.iterations for j in jobs], np.float32),
@@ -1041,36 +1000,12 @@ def stack_traces(traces: Sequence[Dict[str, np.ndarray]]) -> Dict[str, np.ndarra
 
 
 def simulate_jobs(jobs, cfg: JaxSimConfig, fusion: object = "all") -> Dict[str, np.ndarray]:
-    """One fluid simulation of a fixed job list; numpy outputs."""
-    out = simulate_trace(trace_from_jobs(jobs, fusion=fusion), cfg)
+    """One fluid simulation of a fixed job list (a one-lane batch);
+    numpy outputs."""
+    out = simulate_traces_batched(
+        stack_traces([trace_from_jobs(jobs, fusion=fusion)]), cfg)
     return {
-        "jct": np.asarray(out["jct"]),
-        "finished": np.asarray(out["finished"]),
-        "makespan": float(out["makespan"]),
-    }
-
-
-def monte_carlo_jct(
-    n_seeds: int = 16,
-    n_jobs: int = 64,
-    policy: str = "ada",
-    base_seed: int = 0,
-    **cfg_kw,
-) -> Dict[str, np.ndarray]:
-    """vmap over seeds; returns mean/std of avg-JCT across sampled traces.
-
-    Sampling is one vmapped jit; the simulation runs through the chunked
-    batched driver — no per-seed recompiles or redundant jit nesting."""
-    cfg = JaxSimConfig(policy=policy, **cfg_kw)
-    keys = jax.random.split(jax.random.PRNGKey(base_seed), n_seeds)
-    traces = jax.vmap(lambda k: sample_trace(k, n_jobs))(keys)
-    out = simulate_traces_batched(traces, cfg)
-    jct = np.asarray(out["jct"])
-    fin = np.asarray(out["finished"])
-    avg = np.array([jct[i][fin[i]].mean() for i in range(n_seeds)])
-    return {
-        "avg_jct_mean": float(avg.mean()),
-        "avg_jct_std": float(avg.std()),
-        "per_seed": avg,
-        "finished_frac": float(fin.mean()),
+        "jct": np.asarray(out["jct"][0]),
+        "finished": np.asarray(out["finished"][0]),
+        "makespan": float(out["makespan"][0]),
     }

@@ -375,11 +375,11 @@ def gating_fixed_point(
 ):
     """One-shot fixed point of the per-step greedy re-gating loop.
 
-    The fluid backend's bucketed (WFBP) traces used to run FOUR sequential
-    gating rounds per step — start the smallest-remaining-service eligible
-    candidate, recompute the contention state, repeat — mirroring the event
-    backend's re-evaluate-after-each-start loop.  This computes the greedy
-    closure in a single masked pass instead.
+    The sequential loop starts the smallest-remaining-service eligible
+    candidate, recomputes the contention state and repeats, mirroring the
+    event backend's re-evaluate-after-each-start loop.  This computes the
+    greedy closure in a single masked pass; the fluid backend gates its
+    bucketed (WFBP) traces with it.
 
     Why a single pass suffices — monotonicity of the gating predicate in
     the active set.  Write the threshold predicate for candidate ``i``
@@ -409,12 +409,11 @@ def gating_fixed_point(
       first by the loop against ``A0`` itself — sound by construction.
 
     The returned set ``(r1 & r2) | c1`` therefore never violates a cap or
-    threshold that the sequential loop enforces, and equals the loop's
-    closure whenever the greedy outcome is order-independent (the loop was
-    itself truncated at 4 rounds, so neither side is the untruncated
-    closure in pathological many-simultaneous-barrier steps).  Bit-exact
-    agreement with the 4-round loop across the fusion × policy grid is
-    locked in tests/test_fastpath.py.
+    threshold that the sequential loop enforces (checked on random states
+    in tests/test_netmodel.py), and equals the loop's closure whenever the
+    greedy outcome is order-independent.  Bit-exact agreement with a
+    4-round loop across the fusion × policy grid is locked in
+    tests/test_fastpath.py, where that loop is the test's oracle.
 
     For exact-lookahead k-way policies (``exact_kway=True``) the predicate
     is a cost *comparison* (option A vs option B), not an antitone

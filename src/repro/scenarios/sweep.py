@@ -116,9 +116,10 @@ def fluid_config(
     contention); event placement names map to their gang analogues
     (lwf->consolidate, ff->first_fit, ls->least_loaded, rand->random,
     lwf_rack->rack_pack).  ``fast_kw`` forwards the fast-path knobs
-    (``skip``, ``gating``, ``compact``, ``chunk_steps``) —
-    how the equivalence tests pin e.g. ``gating="rounds", skip=False``.
-    ``max_steps=None`` keeps the config's horizon cap."""
+    (``skip``, ``compact``, ``chunk_steps``), which is how the
+    equivalence tests pin the references ``skip=False`` and
+    ``compact=False``.  ``max_steps=None`` keeps the config's horizon
+    cap."""
     from repro.core.jaxsim import JaxSimConfig
 
     comm = canonical_comm(comm)
@@ -172,7 +173,7 @@ def run_scenario_fluid(
 ) -> Dict[str, object]:
     """Fluid (vectorized JAX) simulation of one scenario instance (the
     scenario's WFBP ``fusion`` spec shapes the bucket planes of the
-    trace — ``"all"`` leaves the legacy trace untouched, bit-for-bit)."""
+    trace — ``"all"`` leaves it monolithic)."""
     from repro.core.jaxsim import simulate_jobs
 
     cfg = fluid_config(

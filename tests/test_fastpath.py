@@ -1,16 +1,15 @@
 """Fast-path equivalence locks for the chunked fluid simulator.
 
-The hot-loop rebuild (``core/jaxsim.py``) introduced three switchable
-mechanisms — the one-shot gating fixed point (``gating="fixedpoint"`` vs
-the legacy 4-round loop), periodic lane/job compaction (``compact``), and
-next-event skipping (``skip``).  This module pins the equivalences the
-refactor promised:
+The fluid step (``core/jaxsim.py``) gates bucketed traces with a one-shot
+closure (``netmodel.gating_fixed_point``) and runs with periodic lane/job
+compaction (``compact``) and next-event skipping (``skip``).  This module
+pins the equivalences these promise:
 
-* fixed point vs rounds: bit-exact metrics on the fusion x policy grid
-  (both sides run ``skip=False`` — the two gating variants define the
-  conservative ``leftover`` mask differently, which legitimately changes
-  *which* ticks the skipper may jump, so skip must be held constant for a
-  bit-exact comparison);
+* closure vs the sequential 4-round re-gating loop it replaced
+  (:func:`_legacy_rounds`, patched in for a second run): bit-exact
+  metrics on the fusion x policy grid (both sides run ``skip=False``:
+  the skipper reads which candidates were left unstarted, so skip is held
+  off for a bit-exact comparison);
 * compaction on vs off: bit-exact metrics on two registry cells (lane
   retirement + job-axis trimming are pure re-indexing);
 * recompile guard: the whole 6-policy gating matrix shares at most two
@@ -23,12 +22,14 @@ refactor promised:
 import os
 import sys
 
+import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-from repro.core import jaxsim
+from repro.core import jaxsim, netmodel
 from repro.core.jaxsim import (
     simulate_traces_batched,
     stack_traces,
@@ -59,32 +60,61 @@ def _assert_identical(a, b):
     np.testing.assert_array_equal(a["makespan"], b["makespan"])
 
 
+def _legacy_rounds(r1, priority, loads, counts, overlap, active, rem,
+                   new_cost, max_ways, threshold_gated, dual_threshold, *,
+                   exact_kway=False, eta_over_b=None):
+    """The sequential re-gating loop, with the signature of
+    ``netmodel.gating_fixed_point``: from the candidates ``r1``, each of
+    4 rounds starts the smallest-``priority`` one that passes against the
+    active set as it stands, this tick's earlier starts included."""
+    idx = jnp.arange(r1.shape[-1])
+    started = r1 & (idx == jnp.argmin(jnp.where(r1, priority, jnp.inf)))
+    for _ in range(3):
+        active_now = active | started
+        k_would = netmodel.domain_k(
+            loads, netmodel.domain_counts(loads, active_now), extra=1)
+        olds = overlap & active_now[None, :]
+        min_old_rem = jnp.where(olds, rem[None, :], jnp.inf).min(axis=1)
+        exact = (dict(exact_kway_olds=olds, rem=rem, eta_over_b=eta_over_b)
+                 if exact_kway else {})
+        ok = r1 & ~started & netmodel.may_start_dynamic(
+            k_would, new_cost, min_old_rem, max_ways, threshold_gated,
+            dual_threshold, **exact)
+        started = started | (ok & (idx == jnp.argmin(
+            jnp.where(ok, priority, jnp.inf))))
+    return started
+
+
+def _shipped_and_rounds(monkeypatch, **kw):
+    """One ``model_zoo`` cell as shipped and with :func:`_legacy_rounds`
+    in place of the closure; the jit caches are cleared around the
+    patched run so that neither side reuses the other's program."""
+    shipped = _run_cell("model_zoo", **kw)
+    jax.clear_caches()
+    with monkeypatch.context() as m:
+        m.setattr(netmodel, "gating_fixed_point", _legacy_rounds)
+        rounds = _run_cell("model_zoo", **kw)
+    jax.clear_caches()
+    return shipped, rounds
+
+
 class TestGatingFixedPoint:
-    """One-shot analytic gating == the legacy 4-round re-gating loop."""
+    """One-shot gating closure == the sequential 4-round re-gating loop."""
 
     @pytest.mark.parametrize("fusion", ["none", 16e6])
     @pytest.mark.parametrize("comm", ["ada", "srsf2", "kway2"])
-    def test_bit_exact_on_fusion_policy_grid(self, fusion, comm):
-        kw = dict(seeds=(0, 1), comm=comm, fusion=fusion, skip=False)
-        fp = _run_cell("model_zoo", gating="fixedpoint", **kw)
-        rounds = _run_cell("model_zoo", gating="rounds", **kw)
+    def test_bit_exact_on_fusion_policy_grid(self, fusion, comm, monkeypatch):
+        fp, rounds = _shipped_and_rounds(
+            monkeypatch, seeds=(0, 1), comm=comm, fusion=fusion, skip=False)
         _assert_identical(fp, rounds)
         assert fp["finished"].any()  # the cell actually exercises gating
 
-    def test_monolithic_trace_unaffected_by_gating_knob(self):
-        # fusion="all" has one bucket: the wfbp closure never runs, so the
-        # knob must be inert there (same compiled mono path)
-        kw = dict(seeds=(0,), comm="ada", fusion="all", skip=False)
-        fp = _run_cell("model_zoo", gating="fixedpoint", **kw)
-        rounds = _run_cell("model_zoo", gating="rounds", **kw)
+    def test_monolithic_trace_unaffected_by_gating_knob(self, monkeypatch):
+        # fusion="all" has one bucket: the closure never runs, so swapping
+        # it must leave the monolithic path's results as they are
+        fp, rounds = _shipped_and_rounds(
+            monkeypatch, seeds=(0,), comm="ada", fusion="all", skip=False)
         _assert_identical(fp, rounds)
-
-    def test_unknown_gating_rejected(self):
-        scn = get_scenario("smoke")
-        with pytest.raises(ValueError, match="gating"):
-            cfg = fluid_config(scn, gating="psychic")
-            batch = stack_traces([trace_from_jobs(scn.job_list())])
-            simulate_traces_batched(batch, cfg)
 
 
 class TestCompaction:

@@ -225,6 +225,69 @@ class TestKwayExactStart:
             np.testing.assert_allclose(closed, ref, rtol=1e-9)
 
 
+class TestGatingFixedPoint:
+    """Random states against the claim of ``gating_fixed_point``: its
+    start set never violates a cap or threshold that the sequential loop
+    enforces, under the threshold policies (``max_ways`` 1-3, gated or
+    not)."""
+
+    def _state(self, rng, n_jobs=12, n_domains=8):
+        """A random gating state: domain loads, an in-flight set, the
+        waiting transfers apart from it, remainders and priorities."""
+        loads = rng.random((n_jobs, n_domains)) < 0.3
+        active = rng.random(n_jobs) < 0.35
+        waiting = ~active & (rng.random(n_jobs) < 0.6)
+        rem = rng.uniform(0.05, 10.0, n_jobs).astype(np.float32)
+        new_cost = rng.uniform(0.05, 10.0, n_jobs).astype(np.float32)
+        priority = rng.uniform(1.0, 1e4, n_jobs).astype(np.float32)
+        loads_f = loads.astype(np.float32)
+        overlap = (loads_f @ loads_f.T) > 0
+        return loads, active, waiting, rem, new_cost, priority, overlap
+
+    @staticmethod
+    def _passes(i, in_flight, loads, overlap, rem, new_cost, policy):
+        """Candidate ``i``'s predicate against the in-flight set."""
+        counts = netmodel.domain_counts(loads, in_flight)
+        k_would = netmodel.domain_k(loads, counts, extra=1)[i]
+        olds = overlap[i] & in_flight
+        min_old = rem[olds].min() if olds.any() else np.inf
+        return bool(netmodel.may_start_dynamic(
+            k_would, new_cost[i], min_old, *policy))
+
+    @pytest.mark.parametrize("gated", [False, True])
+    @pytest.mark.parametrize("max_ways", [1, 2, 3])
+    def test_start_set_is_sound_for_the_sequential_loop(self, max_ways, gated):
+        rng = np.random.default_rng(100 * max_ways + gated)
+        policy = (np.float32(max_ways), np.asarray(gated), P.dual_threshold)
+        n_nonempty = 0
+        for _ in range(300):
+            loads, active, waiting, rem, new_cost, priority, overlap = (
+                self._state(rng))
+            n = len(active)
+            r1 = waiting & np.array([
+                self._passes(i, active, loads, overlap, rem, new_cost, policy)
+                for i in range(n)])
+            if not r1.any():
+                continue
+            n_nonempty += 1
+            start = netmodel.gating_fixed_point(
+                r1, priority, loads, netmodel.domain_counts(loads, active),
+                overlap, active, rem, new_cost, *policy)
+            assert not (start & ~r1).any()
+            head = int(np.argmin(np.where(r1, priority, np.inf)))
+            assert start[head]
+            # the head first, then the rest in priority order: each start
+            # passes against the in-flight set as it stands
+            in_flight = active.copy()
+            rest = [i for i in np.argsort(priority, kind="stable")
+                    if start[i] and i != head]
+            for i in [head] + rest:
+                assert self._passes(i, in_flight, loads, overlap, rem,
+                                    new_cost, policy), (i, start)
+                in_flight[i] = True
+        assert n_nonempty >= 100
+
+
 class TestPlacementRank:
     FREE = np.array([1.0, 4.0, 0.0, 2.0])
     LOAD = np.array([9.0, 0.0, 5.0, 2.0])

@@ -1,4 +1,4 @@
-"""The fluid step core (``kernels/fluidstep/core.py``) against a plain
+"""The fluid step core (``jaxsim.fluid_step_core``) against a plain
 NumPy evaluation of the same definitions, and under ``vmap``.
 
 The plain evaluation walks jobs and domains one by one, straight from
@@ -21,7 +21,7 @@ import pytest
 import jax
 import jax.numpy as jnp
 
-from repro.kernels.fluidstep import fluid_step_core
+from repro.core.jaxsim import fluid_step_core
 
 B, ETA = 7e-10, 3e-10
 

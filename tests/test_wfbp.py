@@ -412,7 +412,9 @@ class TestFluidWfbp:
         planes at all — the generalized state machine collapses exactly."""
         import numpy as np
 
-        from repro.core.jaxsim import simulate_trace, trace_from_jobs
+        from repro.core.jaxsim import (
+            simulate_traces_batched, stack_traces, trace_from_jobs,
+        )
         from repro.scenarios.sweep import fluid_config
 
         scn = get_scenario("smoke")
@@ -420,11 +422,9 @@ class TestFluidWfbp:
         plain = trace_from_jobs(scn.job_list())
         planes = dict(plain)
         planes["bucket_bytes"] = plain["msg_bytes"][:, None]
-        import jax.numpy as jnp
-
-        planes["n_buckets"] = jnp.ones((scn.n_jobs,), jnp.int32)
-        a = simulate_trace(plain, cfg)
-        b = simulate_trace(planes, cfg)
+        planes["n_buckets"] = np.ones((scn.n_jobs,), np.int32)
+        a = simulate_traces_batched(stack_traces([plain]), cfg)
+        b = simulate_traces_batched(stack_traces([planes]), cfg)
         np.testing.assert_array_equal(np.asarray(a["jct"]), np.asarray(b["jct"]))
 
     @pytest.mark.parametrize("fusion", ["none", 32e6])
