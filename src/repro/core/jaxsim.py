@@ -742,7 +742,7 @@ def _next_pow2(n: int) -> int:
 #: query-wide counters of :func:`_drive_batched`, returned beside its
 #: results and kept in each fluid ``RunMetrics``
 DRIVER_COUNTERS = ("chunks", "lane_slots", "live_lane_slots", "compactions",
-                   "shapes")
+                   "shapes", "drained_launches")
 
 
 def _drive_batched(traces: Dict[str, jnp.ndarray], cfg: JaxSimConfig,
@@ -751,12 +751,27 @@ def _drive_batched(traces: Dict[str, jnp.ndarray], cfg: JaxSimConfig,
     lane/job/bucket compaction.  ``cfg`` is the policy-stripped static
     key from :func:`_policy_args`.  Returns numpy result planes shaped
     like the input batch, plus the :data:`DRIVER_COUNTERS`: ``chunks``
-    (scan segments launched), ``lane_slots`` and ``live_lane_slots``
-    (lanes at each launch, summed over launches: all of them, padding
-    included, and those not yet retired), ``compactions`` (re-gathers of
-    the batch) and ``shapes`` (distinct ``(lanes, jobs, buckets)`` shapes
-    launched).  On a fabric with uplink domains it also returns, per
-    lane, the :data:`UPLINK_COUNTERS`, pulled as the lane retires.
+    (scan segments launched, the speculative last one included),
+    ``lane_slots`` and ``live_lane_slots`` (lanes at each launch, summed
+    over launches: all of them, padding included, and those not yet
+    retired), ``compactions`` (re-gathers of the batch), ``shapes``
+    (distinct ``(lanes, jobs, buckets)`` shapes launched) and
+    ``drained_launches`` (launches after the first whose input chunk had
+    already finished on the device: the host did not keep ahead).  On a
+    fabric with uplink domains it also returns, per lane, the
+    :data:`UPLINK_COUNTERS`, pulled as the lane retires.
+
+    One chunk is kept in flight: chunk ``k + 1`` is launched on chunk
+    ``k``'s output before chunk ``k``'s done flags are read, so the host
+    syncs, retires and decides on compaction while the device runs.  A
+    finished lane is frozen inside the scan, so the chunk a lane runs
+    past its end changes no bit.  Lanes are retired from chunk ``k``'s
+    state, which ``orig`` indexes; a compaction decided on chunk ``k``'s
+    flags gathers from chunk ``k + 1``'s, at the same shapes as a
+    synchronous loop would, and a lane that finished in chunk ``k + 1``
+    is retired at the next sync.  Once every lane is done the chunk
+    launched last is not read, only waited for, so that no device work
+    of this call outlasts it: one chunk more than the synchronous loop.
 
     Each host phase sits in a profiler span (``jax.profiler.
     TraceAnnotation``, a no-op of about a microsecond when no trace is
@@ -764,7 +779,8 @@ def _drive_batched(traces: Dict[str, jnp.ndarray], cfg: JaxSimConfig,
     ``fluid.launch`` (with ``uplinks``, the fabric's uplink domains),
     ``fluid.sync``, ``fluid.retire`` when lanes finish, and
     ``fluid.compact`` when finished lanes are considered for
-    compaction."""
+    compaction.  Every launch has its sync, the speculative last chunk's
+    included."""
     span = jax.profiler.TraceAnnotation
     with span("fluid.init"):
         arrival0 = np.asarray(traces["arrival"], np.float32)
@@ -787,19 +803,24 @@ def _drive_batched(traces: Dict[str, jnp.ndarray], cfg: JaxSimConfig,
     orig = np.arange(n_lanes0)  # current lane -> original row (-1 = retired)
 
     while True:
-        chunk = results["chunks"]
+        launched = results["chunks"]
         n_lanes_cur, n_jobs_cur = (int(d) for d in traces["arrival"].shape)
         shapes.add((n_lanes_cur, n_jobs_cur,
                     int(traces["bucket_bytes"].shape[-1]) if wfbp else 1))
         results["lane_slots"] += n_lanes_cur
         results["live_lane_slots"] += int((orig >= 0).sum())
-        with span("fluid.launch", chunk=chunk, lanes=n_lanes_cur,
+        if launched and state["n_done"].is_ready():
+            results["drained_launches"] += 1
+        with span("fluid.launch", chunk=launched, lanes=n_lanes_cur,
                   jobs=n_jobs_cur, uplinks=n_uplinks):
-            state = _chunk_jit(traces, state, cfg, max_ways, gated)
+            ahead = _chunk_jit(traces, state, cfg, max_ways, gated)
         results["chunks"] += 1
+        if not launched:
+            state = ahead
+            continue
+        chunk = launched - 1  # the chunk that produced ``state``
         with span("fluid.sync", chunk=chunk):
-            n_done = np.asarray(state["n_done"])
-            tick = np.asarray(state["i"])
+            n_done, tick = jax.device_get((state["n_done"], state["i"]))
             done = (n_done >= n_jobs_cur) | (tick >= cfg.max_steps)
             newly = [l for l in np.nonzero(done)[0] if orig[l] >= 0]
         if newly:
@@ -826,17 +847,20 @@ def _drive_batched(traces: Dict[str, jnp.ndarray], cfg: JaxSimConfig,
                         results[k][row] = v[l]
                     orig[l] = -1
         if done.all():
+            with span("fluid.sync", chunk=launched):
+                ahead["n_done"].block_until_ready()
             break
+        state = ahead
         if not (cfg.compact and done.any()):
             continue
 
         # ---- compaction: retire finished lanes, shrink the batch --------
         # Shapes are bucketed (pow2 lanes, jobs in multiples of 8, >= 2
         # buckets) to bound recompiles; dropped lanes are finished (their
-        # results are already finalized) and dropped job columns are
-        # all-invalid across the surviving lanes, so results are
-        # unchanged bit-for-bit (padded jobs are inert in every
-        # reduction of the step).
+        # results are already finalized, and they stay frozen through the
+        # chunk in flight) and dropped job columns are all-invalid across
+        # the surviving lanes, so results are unchanged bit-for-bit
+        # (padded jobs are inert in every reduction of the step).
         with span("fluid.compact", chunk=chunk):
             live = np.nonzero(~done)[0]
             n_live = len(live)
@@ -893,11 +917,12 @@ def simulate_traces_batched(traces: Dict[str, jnp.ndarray], cfg: JaxSimConfig):
     ``scenarios.sweep.monte_carlo_fluid`` does).  Returns per-lane jct/finished
     arrays, a per-lane makespan vector, the driver's query-wide
     counters (:data:`DRIVER_COUNTERS`: scan chunks launched, lane slots,
-    compactions, shapes) and, on a fabric with uplinks, the per-lane
-    :data:`UPLINK_COUNTERS`.  The gating policy enters the jitted graph
-    as runtime scalars (:func:`_policy_args`), so sweeping policies over
-    one trace shape reuses one compilation; finished lanes retire between
-    chunks (``cfg.compact``) so stragglers don't pay full batch width."""
+    compactions, shapes, drained launches) and, on a fabric with uplinks,
+    the per-lane :data:`UPLINK_COUNTERS`.  The gating policy enters the
+    jitted graph as runtime scalars (:func:`_policy_args`), so sweeping
+    policies over one trace shape reuses one compilation; finished lanes
+    retire between chunks (``cfg.compact``) so stragglers don't pay full
+    batch width."""
     max_ways, gated, cfg_key = _policy_args(cfg)
     out = _drive_batched(
         {k: jnp.asarray(v) for k, v in traces.items()}, cfg_key, max_ways, gated

@@ -99,8 +99,13 @@ class RunMetrics:
     stretch_frac: float = math.nan
     gating_frac: float = math.nan
     #: fluid backend: scan chunks the batched driver launched for the
-    #: batch this run was part of (0 = event backend)
+    #: batch this run was part of, one speculative chunk after the last
+    #: that finished a lane included (0 = event backend)
     chunks: int = 0
+    #: fluid backend, for the same batch: launches after the first at
+    #: which the chunk launched before had already finished on the device,
+    #: so the chip ran dry while the host caught up (0 = event backend)
+    drained_launches: int = 0
     #: fluid backend, for the same batch (0 = event backend): lanes at
     #: each chunk's launch summed over chunks, padding included
     #: (``lane_slots``) and of those the lanes not yet retired
@@ -163,6 +168,7 @@ def from_jcts(
     stretch_frac: float = math.nan,
     gating_frac: float = math.nan,
     chunks: int = 0,
+    drained_launches: int = 0,
     lane_slots: int = 0,
     live_lane_slots: int = 0,
     compactions: int = 0,
@@ -202,6 +208,7 @@ def from_jcts(
         stretch_frac=stretch_frac,
         gating_frac=gating_frac,
         chunks=chunks,
+        drained_launches=drained_launches,
         lane_slots=lane_slots,
         live_lane_slots=live_lane_slots,
         compactions=compactions,

@@ -12,6 +12,9 @@ pins the equivalences these promise:
   off for a bit-exact comparison);
 * compaction on vs off: bit-exact metrics on two registry cells (lane
   retirement + job-axis trimming are pure re-indexing);
+* the driver's one chunk in flight vs a synchronous launch-read-retire
+  loop (:func:`_synchronous_driver`): bit-exact results and uplink
+  counters, one chunk more;
 * recompile guard: the whole 6-policy gating matrix shares at most two
   compiled chunk graphs per trace shape (threshold policies ride the
   dynamic-policy sentinel, exact k-way the second graph);
@@ -39,9 +42,9 @@ from repro.scenarios import QUICK_OVERRIDES, get_scenario
 from repro.scenarios.sweep import FLUID_POLICIES, fluid_config
 
 
-def _run_cell(scn_name, seeds, comm="ada", placement="lwf", fusion=None,
-              dt=0.05, **fast_kw):
-    """One batched fluid run of a registry cell, as plain numpy arrays."""
+def _cell(scn_name, seeds, comm="ada", placement="lwf", fusion=None,
+          dt=0.05, **fast_kw):
+    """A registry cell's stacked batch and fluid config."""
     over = QUICK_OVERRIDES.get(scn_name, {})
     scns = [get_scenario(scn_name, seed=s, **over) for s in seeds]
     cfg = fluid_config(scns[0], comm=comm, placement=placement, dt=dt,
@@ -50,7 +53,12 @@ def _run_cell(scn_name, seeds, comm="ada", placement="lwf", fusion=None,
     batch = stack_traces(
         [trace_from_jobs(s.job_list(), fusion=fus) for s in scns]
     )
-    out = simulate_traces_batched(batch, cfg)
+    return batch, cfg
+
+
+def _run_cell(scn_name, seeds, **kw):
+    """One batched fluid run of a registry cell, as plain numpy arrays."""
+    out = simulate_traces_batched(*_cell(scn_name, seeds, **kw))
     return {k: np.asarray(v) for k, v in out.items()}
 
 
@@ -132,6 +140,86 @@ class TestCompaction:
         on = _run_cell("model_zoo", compact=True, **kw)
         off = _run_cell("model_zoo", compact=False, **kw)
         _assert_identical(on, off)
+
+
+def _synchronous_driver(batch, cfg):
+    """The batched driver as a synchronous loop: launch a chunk, read the
+    whole state back, retire the lanes that finished in it; no
+    compaction.  Returns the result planes, the per-lane uplink
+    counters where the fabric has them, and the chunks launched."""
+    max_ways, gated, key = jaxsim._policy_args(cfg)
+    traces = {k: jnp.asarray(v) for k, v in batch.items()}
+    state = jaxsim._init_jit(traces, key)
+    n_lanes, n_jobs = batch["arrival"].shape
+    counters = [k for k in jaxsim.UPLINK_COUNTERS if k in state]
+    out = {"jct": np.full((n_lanes, n_jobs), np.inf, np.float32),
+           "finished": np.zeros((n_lanes, n_jobs), bool),
+           "makespan": np.zeros((n_lanes,), np.float32),
+           **{k: np.zeros((n_lanes,), np.int64) for k in counters}}
+    retired = np.zeros((n_lanes,), bool)
+    chunks = 0
+    while not retired.all():
+        state = jaxsim._chunk_jit(traces, state, key, max_ways, gated)
+        chunks += 1
+        st = jax.device_get(state)
+        done = (st["n_done"] >= n_jobs) | (st["i"] >= key.max_steps)
+        for lane in np.nonzero(done & ~retired)[0]:
+            fin = (st["phase"][lane] == jaxsim.DONE) & batch["valid"][lane]
+            out["jct"][lane] = st["finish"][lane] - batch["arrival"][lane]
+            out["finished"][lane] = fin
+            out["makespan"][lane] = (st["finish"][lane][fin].max()
+                                     if fin.any() else st["t"][lane])
+            for k in counters:
+                out[k][lane] = st[k][lane]
+        retired |= done
+    return out, chunks
+
+
+#: (cell, seeds, keywords): the paper's NICs, a two-tier fabric with
+#: uplink counters, and WFBP bucketed traces (bucket trim on compaction)
+DRIVER_CELLS = {
+    "paper": ("paper", (0, 1, 2), {}),
+    "oversub_fabric": ("oversub_fabric", (0, 1, 2), {}),
+    "model_zoo_wfbp": ("model_zoo", (0, 1, 2),
+                       dict(comm="srsf2", fusion=16e6)),
+}
+
+
+class TestChunkInFlight:
+    """The driver launches chunk k+1 before it reads chunk k's flags: the
+    results are the synchronous loop's to the bit, with one chunk more."""
+
+    @pytest.mark.parametrize("compact", [True, False])
+    @pytest.mark.parametrize("cell", sorted(DRIVER_CELLS))
+    def test_bit_exact_against_synchronous_loop(self, cell, compact):
+        name, seeds, kw = DRIVER_CELLS[cell]
+        batch, cfg = _cell(name, seeds, compact=compact, **kw)
+        want, chunks = _synchronous_driver(batch, cfg)
+        got = simulate_traces_batched(batch, cfg)
+        uplinks = set(want) - {"jct", "finished", "makespan"}
+        assert uplinks == (set(jaxsim.UPLINK_COUNTERS)
+                           if name == "oversub_fabric" else set())
+        assert all(want[k].sum() > 0 for k in uplinks)
+        for k, v in want.items():
+            np.testing.assert_array_equal(np.asarray(got[k]), v, err_msg=k)
+        assert want["finished"].any()
+        assert got["chunks"] == chunks + 1
+        assert 0 <= got["drained_launches"] <= got["chunks"] - 1
+        assert (got["compactions"] >= 1) == compact
+
+    def test_drained_launches_count_a_chunk_waited_for(self, monkeypatch):
+        """A chunk program that returns only when its chunk has finished
+        drains the device before every launch after the first."""
+        chunk_jit = jaxsim._chunk_jit
+
+        def waited_for(*args, **kw):
+            return jax.block_until_ready(chunk_jit(*args, **kw))
+
+        monkeypatch.setattr(jaxsim, "_chunk_jit", waited_for)
+        name, seeds, kw = DRIVER_CELLS["paper"]
+        out = simulate_traces_batched(*_cell(name, seeds, compact=False, **kw))
+        assert out["chunks"] >= 2
+        assert out["drained_launches"] == out["chunks"] - 1
 
 
 class TestRecompileGuard:
